@@ -31,6 +31,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.kernels import ref, registry, timings
+from repro.launch import xla_setup
 
 
 def _native_lowerings() -> list:
@@ -139,6 +140,7 @@ def main():
                          "--smoke runs never record)")
     ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args()
+    xla_setup.configure()
     iters = args.iters or (5 if args.smoke else 20)
     result = run(smoke=args.smoke, interpret=args.interpret, iters=iters,
                  record=args.record)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from benchmarks import common
 from repro import configs
 from repro import core as silvia
-from repro.launch import serve
+from repro.launch import serve, xla_setup
 from repro.models import lm
 from repro.quant.qtensor import quantize_tree_for_serving
 
@@ -112,6 +112,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes / few iters (CI)")
     args = ap.parse_args()
+    xla_setup.configure()
     result = run(smoke=args.smoke)
     print(json.dumps(result, indent=2))
     common.write_bench_json(result, "pipeline_overhead")

@@ -8,6 +8,8 @@ metric for that table: Ops/Unit + unit counts, or manual-vs-auto parity).
 """
 import argparse
 
+from repro.launch import xla_setup
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -15,6 +17,7 @@ def main() -> None:
                     help="also print the dry-run roofline table (requires "
                          "results/dryrun_baseline.json)")
     args = ap.parse_args()
+    xla_setup.configure()
 
     from benchmarks import table1a, table1b, table2_cnn
     from benchmarks.common import print_rows

@@ -45,7 +45,7 @@ import numpy as np
 from benchmarks import common
 from benchmarks.serve_throughput import FAMILY_ARCHS
 from repro import configs
-from repro.launch import scheduler, serve
+from repro.launch import scheduler, serve, xla_setup
 from repro.launch.engine import ServeEngine
 from repro.launch.frontend import AsyncFrontend
 from repro.models import lm
@@ -268,6 +268,7 @@ def main():
                     help="seed for the method-mix traffic trace "
                          "(baselines use the default 0)")
     args = ap.parse_args()
+    xla_setup.configure()
     result = run(smoke=args.smoke, silvia_passes=args.silvia,
                  family=args.family, n_requests=args.n_requests,
                  rate=args.rate, trace_seed=args.trace_seed)

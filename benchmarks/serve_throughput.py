@@ -81,7 +81,7 @@ from repro import configs
 from repro.distributed import context as dctx
 from repro.distributed import elastic
 from repro.kernels import registry
-from repro.launch import resilience, scheduler, serve
+from repro.launch import resilience, scheduler, serve, xla_setup
 from repro.launch.engine import ServeEngine
 from repro.launch.mesh import make_mesh
 from repro.models import lm
@@ -472,6 +472,7 @@ def main():
                          "trace (one knob for BOTH builders; baselines "
                          "use the default 0)")
     args = ap.parse_args()
+    xla_setup.configure()
     mesh = parse_mesh(args.mesh) if args.mesh else None
     if mesh is not None and mesh[0] * mesh[1] > jax.device_count():
         raise SystemExit(

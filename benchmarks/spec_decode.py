@@ -30,7 +30,7 @@ import numpy as np
 
 from benchmarks import common
 from repro import configs
-from repro.launch import scheduler
+from repro.launch import scheduler, xla_setup
 from repro.launch.engine import ServeEngine, SpecDecodeConfig
 from repro.models import lm
 
@@ -129,6 +129,7 @@ def main() -> int:
                     help="seed for the traffic trace (baselines use the "
                          "default 0)")
     args = ap.parse_args()
+    xla_setup.configure()
     result = run(smoke=args.smoke, family=args.family, k=args.k,
                  n_requests=args.n_requests, trace_seed=args.trace_seed)
     print(json.dumps(result, indent=2))

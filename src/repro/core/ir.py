@@ -77,18 +77,7 @@ def alap_schedule(eqns: Sequence, outvars: Sequence) -> list:
     n = len(eqns)
     if n == 0:
         return list(eqns)
-    def_idx, _ = defs_uses(eqns, outvars)
-    # consumers[i] = eqn indices that must come after eqn i
-    consumers: list[set[int]] = [set() for _ in range(n)]
-    prev_effectful = None
-    for i, eqn in enumerate(eqns):
-        for v in eqn.invars:
-            if not is_literal(v) and v in def_idx:
-                consumers[def_idx[v]].add(i)
-        if eqn.effects:
-            if prev_effectful is not None:
-                consumers[prev_effectful].add(i)
-            prev_effectful = i
+    consumers = dependencies(eqns, outvars)
     # ALAP level: each eqn sits at min(consumer levels) - 1; eqns consumed
     # only by the BB outputs sit at level n.  Stable sort by (level,
     # original index) realizes the latest legal schedule.
@@ -101,7 +90,26 @@ def alap_schedule(eqns: Sequence, outvars: Sequence) -> list:
     return [eqns[i] for i in idx]
 
 
-def _topo_order(consumers, n):
+def dependencies(eqns: Sequence, outvars: Sequence) -> list:
+    """consumers[i] = eqn indices that must come after eqn i (data
+    dependencies, plus program order among effectful eqns)."""
+    def_idx, _ = defs_uses(eqns, outvars)
+    consumers: list[set[int]] = [set() for _ in range(len(eqns))]
+    prev_effectful = None
+    for i, eqn in enumerate(eqns):
+        for v in eqn.invars:
+            if not is_literal(v) and v in def_idx:
+                consumers[def_idx[v]].add(i)
+        if eqn.effects:
+            if prev_effectful is not None:
+                consumers[prev_effectful].add(i)
+            prev_effectful = i
+    return consumers
+
+
+def _kahn(consumers, n) -> list:
+    """Topological order of nodes 0..n-1; shorter than n iff there is a
+    cycle."""
     indeg = [0] * n
     for i in range(n):
         for j in consumers[i]:
@@ -115,8 +123,32 @@ def _topo_order(consumers, n):
             indeg[j] -= 1
             if indeg[j] == 0:
                 stack.append(j)
+    return out
+
+
+def _topo_order(consumers, n):
+    out = _kahn(consumers, n)
     assert len(out) == n, "dependency cycle in jaxpr (impossible)"
     return out
+
+
+def merged_acyclic(consumers: list, groups) -> bool:
+    """Does a schedule with these `dependencies` stay acyclic when each
+    group of eqn indices is merged into one node?  Packing a tuple
+    replaces its covered eqns with one packed item, so two tuples that
+    each read a value the other defines cannot both be packed, even though
+    each tuple alone is legal."""
+    node = list(range(len(consumers)))
+    for g, members in enumerate(groups):
+        for i in members:
+            node[i] = len(consumers) + g
+    n = len(consumers) + len(groups)
+    merged: list[set[int]] = [set() for _ in range(n)]
+    for i, cons in enumerate(consumers):
+        for j in cons:
+            if node[i] != node[j]:
+                merged[node[i]].add(node[j])
+    return len(_kahn(merged, n)) == n
 
 
 # ---------------------------------------------------------------------------

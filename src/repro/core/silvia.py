@@ -212,6 +212,7 @@ class SILVIA:
             tuples, dropped = self._filter_ii_tuples(tuples, ctx, ctx.closed,
                                                      loop_info)
             stats["ii_dropped"] = dropped
+        tuples = self._drop_cyclic_tuples(tuples, ctx)
         if not tuples:
             return stats
         stats["tuples"] = len(tuples)
@@ -253,6 +254,22 @@ class SILVIA:
         if ctx.patches == before:
             return closed, stats
         return ir.emit_closed_jaxpr(closed, ctx.eqns), stats
+
+    def _drop_cyclic_tuples(self, tuples, ctx) -> list:
+        """Keep tuples in order while their packed items leave the BB
+        acyclic.  get_tuples checks each tuple against the ORIGINAL
+        schedule only, so tuple A may read a value tuple B defines while B
+        reads one A defines (e.g. {d = a-a, g = a-e} and {e = a+a,
+        f = d+a}); the later tuple of such a pair is not packed."""
+        deps = ir.dependencies(ctx.eqns, ctx.outvars)
+        kept: list[Tuple_] = []
+        groups: list[frozenset] = []
+        for tup in tuples:
+            cover = frozenset().union(*(c.covered for c in tup.cands))
+            if ir.merged_acyclic(deps, groups + [cover]):
+                kept.append(tup)
+                groups.append(cover)
+        return kept
 
     def _filter_ii_tuples(self, tuples, ctx, closed, loop_info):
         """Drop tuples whose packed super-node raises II_min (Fig. 5).

@@ -261,4 +261,5 @@ def plan_degraded_mesh(old_mesh, healthy: Sequence, *, dp_axes: tuple,
         else:
             shape.append(1)
     devs = np.asarray(healthy[:d * m]).reshape(tuple(shape))
-    return jax.sharding.Mesh(devs, old_mesh.axis_names)
+    return jax.sharding.Mesh(devs, old_mesh.axis_names,
+                             axis_types=old_mesh.axis_types)

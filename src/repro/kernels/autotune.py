@@ -249,17 +249,25 @@ def tune(kind: str, *dims: int, candidates=None, iters: int = 3,
 
     best_blk, best_us = default_block(kind), float("inf")
     results = {}
+    first_err = None
     for blk in candidates:
         try:
             us = _time_call(jax.jit(run, static_argnums=0), blk, iters=iters)
-        except Exception:
+        except Exception as e:  # noqa: BLE001
+            first_err = first_err or e
             continue  # candidate illegal on this backend/shape
         results[str(blk)] = round(us, 1)
         if us < best_us:
             best_blk, best_us = blk, us
     if not results:
-        # every candidate failed: don't poison the persistent cache (a hit
-        # would suppress retries forever) -- fall back without recording
+        # every candidate failed.  Natively that is a kernel the device
+        # refuses at every block, which the default would hit too: raise.
+        # In interpret mode fall back without recording (a cache hit would
+        # suppress retries forever)
+        if not interpret:
+            raise RuntimeError(
+                f"autotune: every {kind} block failed on {lowering} for "
+                f"{dims}; first error: {first_err}") from first_err
         return default_block(kind)
     cache = _load()
     cache[_key(kind, *dims, lowering=lowering, interpret=interpret)] = {

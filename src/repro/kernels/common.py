@@ -126,17 +126,6 @@ def mul4_reduce(a32, b32):
     return [p0, p1, p2, p3]
 
 
-def unpack_w4_words(wp):
-    """Packed int4 words [..., N//2] int8 -> [..., N] int8 weights
-    (interleaved columns; inverse of ref.pack_w4's
-    word = (w_even + 8) | (w_odd << 4)).  3 cheap VPU ops per word."""
-    w32 = wp.astype(jnp.int32)
-    w_even = (w32 & 0xF) - 8          # de-bias low nibble -> [-8, 7]
-    w_odd = w32 >> 4                  # arithmetic shift -> [-8, 7]
-    inter = jnp.stack([w_even, w_odd], axis=-1)
-    return inter.reshape(*wp.shape[:-1], 2 * wp.shape[-1]).astype(jnp.int8)
-
-
 def pack_lanes(xs, lane_bits: int):
     """Pack len(xs) == 32//lane_bits narrow int tensors into one uint32 SWAR
     word tensor (bit-concatenation of two's-complement lanes)."""

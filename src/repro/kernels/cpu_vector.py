@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels import common
+from repro.kernels import common, ref
 
 
 def simd_add(xs, ys, *, lane_bits: int = 8, sub: bool = False):
@@ -68,7 +68,7 @@ def packed_w4_matmul(x_q, w_packed, x_scale, w_scale, *,
                      out_dtype=jnp.float32):
     """w4a8 GEMM with vectorized nibble unpack to int8 (not int32 like the
     oracle) feeding the narrow-dtype GEMM."""
-    w = common.unpack_w4_words(w_packed)
+    w = ref.unpack_w4(w_packed)
     acc = lax.dot_general(x_q, w, (((1,), (0,)), ((), ())),
                           preferred_element_type=jnp.int32)
     return (acc.astype(jnp.float32) * x_scale * w_scale).astype(out_dtype)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import autotune, common
+from repro.kernels.ref import W4_HALF, w4_kernel_cols, w4_nibbles
 
 
 def interpret_default() -> bool:
@@ -214,8 +215,14 @@ def quant_matmul(x_q, w_q, x_scale, w_scale, *, out_dtype=jnp.float32,
 
 
 def _pmm_kernel(x_ref, wp_ref, o_ref):
-    w = common.unpack_w4_words(wp_ref[...])
-    o_ref[...] = jnp.dot(x_ref[...], w, preferred_element_type=jnp.int32)
+    x = x_ref[...]
+    for g in range(wp_ref.shape[1] // W4_HALF):
+        lo, hi = w4_nibbles(wp_ref[:, g * W4_HALF:(g + 1) * W4_HALF])
+        c = 2 * g * W4_HALF
+        o_ref[:, c:c + W4_HALF] = jnp.dot(x, lo,
+                                          preferred_element_type=jnp.int32)
+        o_ref[:, c + W4_HALF:c + 2 * W4_HALF] = jnp.dot(
+            x, hi, preferred_element_type=jnp.int32)
 
 
 def packed_w4_matmul_acc(x_q, w_packed, *, block=None,
@@ -232,22 +239,22 @@ def packed_w4_matmul_acc(x_q, w_packed, *, block=None,
         block = autotune.resolve("packed_w4_matmul", m, k, n,
                                  lowering="gpu-pallas", interpret=interpret)
     bm = min(block[0], max(16, m))
-    bn = min(block[1], max(16, n))
-    bn -= bn % 2
-    mp, np_ = common.cdiv(m, bm) * bm, common.cdiv(n, bn) * bn
+    # block[1] counts output columns; a block holds whole packing groups
+    bnh = max(W4_HALF, min(block[1], n) // 2 // W4_HALF * W4_HALF)
+    mp, nhp = common.cdiv(m, bm) * bm, common.cdiv(n_half, bnh) * bnh
     x_p = jnp.pad(x_q, ((0, mp - m), (0, 0)))
-    w_p = jnp.pad(w_packed, ((0, 0), (0, np_ // 2 - n_half)),
+    w_p = jnp.pad(w_packed, ((0, 0), (0, nhp - n_half)),
                   constant_values=0x08)
     out = pl.pallas_call(
         _pmm_kernel,
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        grid=(mp // bm, np_ // bn),
+        out_shape=jax.ShapeDtypeStruct((mp, 2 * nhp), jnp.int32),
+        grid=(mp // bm, nhp // bnh),
         in_specs=[pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-                  pl.BlockSpec((k, bn // 2), lambda i, j: (0, j))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+                  pl.BlockSpec((k, bnh), lambda i, j: (0, j))],
+        out_specs=pl.BlockSpec((bm, 2 * bnh), lambda i, j: (i, j)),
         interpret=interpret,
     )(x_p, w_p)
-    return out[:m, :n]
+    return w4_kernel_cols(out[:m], n)
 
 
 def packed_w4_matmul(x_q, w_packed, x_scale, w_scale, *,

@@ -90,29 +90,82 @@ def quant_matmul_ref(x_q, w_q, x_scale, w_scale, out_dtype=jnp.float32):
 
 def packed_w4_matmul_ref(x_q, w_packed, x_scale, w_scale,
                          out_dtype=jnp.float32):
-    """w4a8 matmul oracle with two int4 weights packed per int8 word.
-
-    w_packed: [K, N//2] int8 storing (w_even + 16 * w_odd) where w_even is
-    biased to unsigned 4-bit (w_even_u = w_even + 8) so the word stays in
-    int8 range; columns 2j / 2j+1 of the logical [K, N] int4 weight matrix.
-
-    The oracle unpacks and performs the exact int32 GEMM.
-    """
-    lo_u = (w_packed.astype(jnp.int32) & 0xF)            # unsigned 4-bit + bias
-    w_even = lo_u - 8                                     # de-bias -> signed
-    w_odd = w_packed.astype(jnp.int32) >> 4               # arithmetic shift
-    k, n_half = w_packed.shape
-    w = jnp.stack([w_even, w_odd], axis=-1).reshape(k, 2 * n_half)
+    """w4a8 matmul oracle with two int4 weights packed per int8 word
+    (the `pack_w4` layout).  The oracle unpacks and performs the exact
+    int32 GEMM."""
+    w = unpack_w4(w_packed).astype(jnp.int32)
     acc = jnp.dot(x_q.astype(jnp.int32), w, preferred_element_type=jnp.int32)
     return (acc.astype(jnp.float32) * x_scale * w_scale).astype(out_dtype)
 
 
+# ---------------------------------------------------------------------------
+# The w4 packing layout.  Every lowering, the quantizer and the kernels
+# take it from here.
+# ---------------------------------------------------------------------------
+
+#: Logical columns per w4 packing group.  Group g covers columns
+#: [g*W4_GROUP, (g+1)*W4_GROUP) and is stored in words
+#: [g*W4_GROUP/2, (g+1)*W4_GROUP/2): the low nibbles hold the group's
+#: first half of the columns, the high nibbles its second half.  A last
+#: group shorter than W4_GROUP splits its columns into halves the same
+#: way.
+W4_GROUP = 256
+#: Words per packing group: one TPU lane tile, so a kernel splits a group
+#: with `w4_nibbles` into two lane-aligned int8 tiles.
+W4_HALF = W4_GROUP // 2
+
+
 def pack_w4(w_int4):
-    """Pack a [K, N] int4-valued (stored int8, range [-8, 7]) weight matrix
-    into [K, N//2] int8 words: word = (w_even + 8) | (w_odd << 4)."""
-    assert w_int4.shape[-1] % 2 == 0
+    """Pack a [..., N] int4-valued (stored int8, range [-8, 7]) weight
+    matrix into [..., N//2] int8 words: per group, word j holds
+    (first-half column j) + 8 in its low nibble and second-half column j
+    in its high nibble."""
+    n = w_int4.shape[-1]
+    assert n % 2 == 0
     w = w_int4.astype(jnp.int32)
-    w_even = w[..., 0::2] + 8          # [0, 15]
-    w_odd = w[..., 1::2]               # [-8, 7]
-    word = (w_odd * 16) + w_even       # in [-128, 127]
-    return word.astype(jnp.int8)
+    lead = w.shape[:-1]
+    main = n - n % W4_GROUP
+    grp = w[..., :main].reshape(*lead, main // W4_GROUP, 2, W4_HALF)
+    words = [(grp[..., 0, :] + 8 + 16 * grp[..., 1, :])
+             .reshape(*lead, main // 2)]
+    tail = w[..., main:]
+    h = tail.shape[-1] // 2
+    words.append(tail[..., :h] + 8 + 16 * tail[..., h:])   # in [-128, 127]
+    return jnp.concatenate(words, axis=-1).astype(jnp.int8)
+
+
+def w4_nibbles(wp):
+    """Packed int4 words -> (low, high) nibble weights as int8 in [-8, 7]:
+    3 cheap VPU ops per word, no relayout."""
+    w32 = wp.astype(jnp.int32)
+    lo = (w32 & 0xF) - 8              # de-bias low nibble
+    hi = w32 >> 4                     # arithmetic shift
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
+
+
+def unpack_w4(wp):
+    """Inverse of pack_w4: [..., N//2] int8 words -> [..., N] int8 in
+    [-8, 7]."""
+    lo, hi = w4_nibbles(wp)
+    lead, nh = lo.shape[:-1], lo.shape[-1]
+    main = nh - nh % W4_HALF
+    g = main // W4_HALF
+    body = jnp.stack([lo[..., :main].reshape(*lead, g, W4_HALF),
+                      hi[..., :main].reshape(*lead, g, W4_HALF)], axis=-2)
+    return jnp.concatenate([body.reshape(*lead, 2 * main), lo[..., main:],
+                            hi[..., main:]], axis=-1)
+
+
+def w4_kernel_cols(out, n: int):
+    """Logical [M, N] columns from a w4 kernel's padded [M, >= N] output.
+    The kernels treat every W4_HALF words as a whole group and write its
+    high-nibble products W4_HALF lanes after its low ones; a shorter last
+    group of r columns therefore has its high half at that fixed offset,
+    not right after its r/2 low columns."""
+    main = n - n % W4_GROUP
+    h = (n - main) // 2
+    if h == 0:
+        return out[:, :n]
+    return jnp.concatenate([out[:, :main + h],
+                            out[:, main + W4_HALF:main + W4_HALF + h]],
+                           axis=-1)

@@ -104,7 +104,6 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import core as silvia
@@ -120,7 +119,7 @@ from repro.launch import resilience as res
 from repro.launch import sampling
 from repro.launch import scheduler
 from repro.launch import serve
-from repro.models import lm
+from repro.models import attention, lm
 from repro.models import slot_state
 
 
@@ -191,14 +190,16 @@ class _MeshPlan:
                 self.slot_axes, self.tp_axes)
 
 
-def _mesh_plan(cfg, spec: slot_state.SlotStateSpec,
-               init_kwargs: dict) -> Optional[_MeshPlan]:
+def _mesh_plan(cfg, spec: slot_state.SlotStateSpec, init_kwargs: dict,
+               params) -> Optional[_MeshPlan]:
     ctx = dctx.current()
     if ctx is None:
         return None
     mesh, dp_axes, model_axis = ctx
     m = mesh.shape[model_axis] if model_axis in mesh.axis_names else 1
     plan = slot_state.tp_plan(cfg, m)
+    if plan.attn:
+        attention.check_w4_tp(params, cfg, m)
     tp_axes = slot_state.tp_axes_for(cfg, m, **init_kwargs) if plan.active \
         else (None,) * len(spec.batch_axes)
     return _MeshPlan(mesh=mesh, dp_axes=tuple(dp_axes),
@@ -349,12 +350,12 @@ def _shard_bundle_fns(plan: _MeshPlan, decode_scan, decode_fn, prefill_fn,
         # the sampling page shards like every other per-slot array: slot
         # axis over dp.  The sampler is per-row (no cross-row reduction),
         # so sharded sampled tokens stay bit-identical to single-device
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp),
-                                 (P(dp),) * 5),
-                       out_specs=(P(None, dp), P(dp), sspecs, P(dp),
-                                  P(dp)),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp),
+                                     (P(dp),) * 5),
+                           out_specs=(P(None, dp), P(dp), sspecs, P(dp),
+                                      P(dp)),
+                           check_vma=False)
         return fn(params, tok, cache, pos, active, samp)
 
     @functools.partial(jax.jit, donate_argnums=(2,))
@@ -366,10 +367,10 @@ def _shard_bundle_fns(plan: _MeshPlan, decode_scan, decode_fn, prefill_fn,
                 params = dshard.gather_sharded(params, pspecs)
                 return decode_fn(params, tok, cache, pos, active)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp)),
-                       out_specs=(P(dp), sspecs),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp)),
+                           out_specs=(P(dp), sspecs),
+                           check_vma=False)
         return fn(params, tok, cache, pos, active)
 
     @functools.partial(jax.jit, static_argnums=(3, 4))
@@ -383,10 +384,10 @@ def _shard_bundle_fns(plan: _MeshPlan, decode_scan, decode_fn, prefill_fn,
                 return prefill_fn(params, prompts, last_positions,
                                   cache_len, enc_pad)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, prspecs, P(dp)),
-                       out_specs=(P(dp), P(dp), sspecs, P(dp)),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, prspecs, P(dp)),
+                           out_specs=(P(dp), P(dp), sspecs, P(dp)),
+                           check_vma=False)
         return fn(params, prompts, last_positions)
 
     @jax.jit
@@ -399,10 +400,10 @@ def _shard_bundle_fns(plan: _MeshPlan, decode_scan, decode_fn, prefill_fn,
                 params = dshard.gather_sharded(params, pspecs)
                 return embed_fn(params, prompts, last_positions)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, prspecs, P(dp)),
-                       out_specs=(P(dp), P(dp)),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, prspecs, P(dp)),
+                           out_specs=(P(dp), P(dp)),
+                           check_vma=False)
         return fn(params, prompts, last_positions)
 
     return segment, chunk_step, prefill, embed
@@ -566,11 +567,11 @@ def _shard_spec_fns(plan: _MeshPlan, spec: slot_state.SlotStateSpec,
                 return draft_scan(params, tok, cache, pos, active, samp,
                                   n_steps)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp),
-                                 (P(dp),) * 5),
-                       out_specs=(P(None, dp), sspecs, snap_specs),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, P(dp), sspecs, P(dp), P(dp),
+                                     (P(dp),) * 5),
+                           out_specs=(P(None, dp), sspecs, snap_specs),
+                           check_vma=False)
         return fn(params, tok, cache, pos, active, samp)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -582,20 +583,20 @@ def _shard_spec_fns(plan: _MeshPlan, spec: slot_state.SlotStateSpec,
                 params = dshard.gather_sharded(params, pspecs)
                 return verify_scan(params, cache, pos, active, samp, xs)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, sspecs, P(dp), P(dp),
-                                 (P(dp),) * 5, P(None, dp)),
-                       out_specs=(P(None, dp), P(dp), sspecs, P(dp),
-                                  P(dp)),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, sspecs, P(dp), P(dp),
+                                     (P(dp),) * 5, P(None, dp)),
+                           out_specs=(P(None, dp), P(dp), sspecs, P(dp),
+                                      P(dp)),
+                           check_vma=False)
         return fn(params, cache, pos, active, samp, xs)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def rollback(cache, snaps, idx):
-        fn = shard_map(rollback_fn, mesh=mesh,
-                       in_specs=(sspecs, snap_specs, P(dp)),
-                       out_specs=sspecs,
-                       check_rep=False)
+        fn = jax.shard_map(rollback_fn, mesh=mesh,
+                           in_specs=(sspecs, snap_specs, P(dp)),
+                           out_specs=sspecs,
+                           check_vma=False)
         return fn(cache, snaps, idx)
 
     return draft, verify, rollback
@@ -741,7 +742,7 @@ class ServeEngine:
         # evenly over the dp shards, so the dp size becomes the batch
         # bucket floor (admission included)
         self._init_kwargs = init_kwargs
-        self._plan = _mesh_plan(cfg, self._spec, init_kwargs)
+        self._plan = _mesh_plan(cfg, self._spec, init_kwargs, params)
         self._adm_floor = 1
         self._health: Optional[delastic.DeviceHealthRegistry] = None
         self._reshard_s = 0.0
@@ -803,7 +804,8 @@ class ServeEngine:
         if spec_decode is not None:
             dcfg = spec_decode.draft_cfg
             self._draft_spec = slot_state.spec_for(dcfg)
-            self._draft_plan = _mesh_plan(dcfg, self._draft_spec, {})
+            self._draft_plan = _mesh_plan(dcfg, self._draft_spec, {},
+                                          spec_decode.draft_params)
             self._draft_bundle = _engine_bundle(dcfg, silvia_passes,
                                                 self._lowerings,
                                                 self._draft_plan)
@@ -862,6 +864,9 @@ class ServeEngine:
             "replayed_tokens", "replay_divergence", "duplicate_rejects",
             "snapshots", "restores", "drains", "degraded",
             "cancelled_queued", "cancelled_inflight")}
+        # the first real (not injected) dispatch error, kept for
+        # diagnosis: recovery turns it into replays and FAILED outcomes
+        self._first_error: Optional[str] = None
         # -- cross-request prefix cache (launch/prefix_cache.py) --
         self._prefix: Optional[pfx.PrefixCache] = None
         if prefix_cache is not None:
@@ -1963,7 +1968,8 @@ class ServeEngine:
             old.mesh, self._health.healthy(), dp_axes=old.dp_axes,
             model_axis=old.model_axis, n_slots=self.n_slots, cfg=self.cfg)
         with dctx.mesh_scope(new_mesh, old.dp_axes, old.model_axis):
-            self._plan = _mesh_plan(self.cfg, self._spec, self._init_kwargs)
+            self._plan = _mesh_plan(self.cfg, self._spec, self._init_kwargs,
+                                    self.params)
         dp = self._plan.dp_size
         scheduler.validate_slot_sharding(self.n_slots, dp)
         self.min_batch_bucket = min(max(self._user_min_batch, dp),
@@ -1977,7 +1983,8 @@ class ServeEngine:
         if self._sd is not None:
             dcfg = self._sd.draft_cfg
             with dctx.mesh_scope(new_mesh, old.dp_axes, old.model_axis):
-                self._draft_plan = _mesh_plan(dcfg, self._draft_spec, {})
+                self._draft_plan = _mesh_plan(dcfg, self._draft_spec, {},
+                                              self._draft_params)
             self._draft_bundle = _engine_bundle(dcfg, self.silvia_passes,
                                                 self._lowerings,
                                                 self._draft_plan)
@@ -2012,6 +2019,8 @@ class ServeEngine:
         key = "faults_injected" if isinstance(exc, SimulatedFailure) \
             else "errors"
         self._robust[key] += 1
+        if key == "errors" and self._first_error is None:
+            self._first_error = f"{type(exc).__name__}: {exc}"
         self._robust["recoveries"] += 1
         if isinstance(exc, delastic.DeviceLoss) and self._plan is not None:
             self._degrade_at.append(now)
@@ -2568,6 +2577,7 @@ class ServeEngine:
             "lowerings": dict(self._lowerings),
             "decode_bundle_lru": serve.decode_cache_info(),
             "robustness": dict(self._robust),
+            "first_error": self._first_error,
             "dispatch_sites": dict(self._site_counts),
             "admission": {
                 "token_budget": self._admit_budget,

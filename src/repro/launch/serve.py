@@ -28,8 +28,10 @@ The serving path is where the paper's technique lives end to end:
   / `cpu-vector` / `ref`, auto-selected per backend.
   ``REPRO_LOWERING=<op>=<id>,...`` (or ``*=<id>``) forces specific
   lowerings -- e.g. ``REPRO_LOWERING='*=ref'`` serves everything on the
-  pure-jnp oracle, bit-identically; the census of active lowerings is
-  printed per run and reported by the engine's ``cache_info()``.
+  pure-jnp oracle, bit-identically (on a TPU with the exact rounding
+  that ``launch/xla_setup.configure()`` sets; DESIGN.md sec. 7); the
+  census of active lowerings is printed per run and reported by the
+  engine's ``cache_info()``.
 
 For ragged multi-request traffic, use the continuous-batching engine
 instead of calling `generate()` per batch (see launch/engine.py and
@@ -51,8 +53,8 @@ graph per (batch bucket, cache-length bucket) serves an ever-changing
 request mix, token-identically to `generate()`.  Constructed under a
 `repro.distributed.context.mesh_scope`, the engine additionally shard_maps
 those segment graphs over the mesh (slot axes over the data axes, probed
-head/state axes over "model") while staying bit-identical -- see
-launch/engine.py and DESIGN.md sec. 7.
+head/state axes over "model") while staying bit-identical, with the same
+exact rounding -- see launch/engine.py and DESIGN.md sec. 7.
 """
 from __future__ import annotations
 
@@ -70,6 +72,7 @@ from repro import configs
 from repro import core as silvia
 from repro.kernels import ops as kops
 from repro.kernels import registry
+from repro.launch import xla_setup
 from repro.launch import sampling as sampling_lib
 from repro.models import lm
 from repro.quant.qtensor import quantize_tree_for_serving
@@ -295,6 +298,7 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    xla_setup.configure()
 
     cfg = configs.get_reduced_config(args.arch) if args.reduced \
         else configs.get_config(args.arch)

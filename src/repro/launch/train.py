@@ -28,6 +28,7 @@ from repro.data import DataConfig, make_stream
 from repro.distributed.fault import (FailureInjector, RestartPolicy,
                                      SimulatedFailure, StragglerDetector)
 from repro.distributed.sharding import param_pspecs, to_shardings
+from repro.launch import xla_setup
 from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.optim import AdamWConfig, adamw_init
@@ -135,6 +136,7 @@ def main():
     ap.add_argument("--max-restarts", type=int, default=10)
     ap.add_argument("--sim-hosts", type=int, default=4)
     args = ap.parse_args()
+    xla_setup.configure()
     run(args)
 
 

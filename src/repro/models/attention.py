@@ -20,6 +20,8 @@ import numpy as np
 from repro.distributed import context as dctx
 from repro.models import common
 from repro.quant.qtensor import QTensor, qmatmul
+from repro.kernels.ref import W4_GROUP
+from repro.quant.quantize import slice_int4_cols
 from repro.models.config import ModelConfig
 
 
@@ -39,9 +41,7 @@ def _tp_slice_cols(w, j, width: int):
     [..., K, N] (w4a8 packs two logical columns per stored word)."""
     if isinstance(w, QTensor):
         if w.fmt == "w4a8":
-            assert width % 2 == 0, (width, "w4a8 needs even column slices")
-            q = jax.lax.dynamic_slice_in_dim(
-                w.q, j * (width // 2), width // 2, axis=w.q.ndim - 1)
+            q = slice_int4_cols(w.q, j * width, width)
         else:
             q = jax.lax.dynamic_slice_in_dim(w.q, j * width, width,
                                              axis=w.q.ndim - 1)
@@ -49,6 +49,26 @@ def _tp_slice_cols(w, j, width: int):
                                              axis=w.scale.ndim - 1)
         return QTensor(q, scale, w.fmt)
     return jax.lax.dynamic_slice_in_dim(w, j * width, width, axis=w.ndim - 1)
+
+
+def check_w4_tp(params, cfg: ModelConfig, size: int) -> None:
+    """Refuse head tensor parallelism over `size` model shards where it
+    would cut a w4a8 q/k/v weight inside a packing group
+    (kernels/ref.pack_w4): each shard's columns must be whole groups, so
+    that its slice is one word range.  qwen1.5-0.5b fits 2 and 4 shards;
+    yi-6b's k/v (4 heads x 128) fit 2, not 4."""
+    widths = {"wq": cfg.q_dim // size, "wk": cfg.kv_dim // size,
+              "wv": cfg.kv_dim // size}
+    for path, w in jax.tree_util.tree_leaves_with_path(
+            params, is_leaf=lambda x: isinstance(x, QTensor)):
+        name = getattr(path[-1], "key", None)
+        if (isinstance(w, QTensor) and w.fmt == "w4a8" and name in widths
+                and widths[name] % W4_GROUP):
+            raise ValueError(
+                f"w4a8 {jax.tree_util.keystr(path)} over {size} model "
+                f"shards gives {widths[name]}-column slices, not whole "
+                f"{W4_GROUP}-column packing groups: use fewer model "
+                f"shards or w8a8")
 
 
 def _tp_gather_heads(out):

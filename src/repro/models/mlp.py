@@ -189,7 +189,6 @@ def moe_shard_map(p, x, cfg: ModelConfig, mesh, dp_axes, model_axis):
     Layout: tokens sharded over dp (replicated over model); experts
     block-assigned to model shards.  Capacity is per-dp-shard (same token
     dropping semantics as grouped dispatch with G = |dp|)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m: MoEConfig = cfg.moe
@@ -251,10 +250,10 @@ def moe_shard_map(p, x, cfg: ModelConfig, mesh, dp_axes, model_axis):
         return yt.reshape(bl, sl, d), aux
 
     wi_spec = P(model_axis, dp, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(wi_spec, wi_spec, P(model_axis, None, dp), P(None, None),
                   P(dp, None, None)),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(p["wi"], p["wg"], p["wo"], p["router"], x)

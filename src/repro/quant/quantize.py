@@ -11,6 +11,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.prims import width_hint
@@ -52,9 +53,15 @@ def pack_int4(q4):
 def unpack_int4(packed):
     """[..., N//2] packed int8 words -> [..., N] int4-valued int8, width-
     hinted for the SILVIA passes."""
-    w32 = packed.astype(jnp.int32)
-    even = (w32 & 0xF) - 8
-    odd = w32 >> 4
-    out = jnp.stack([even, odd], axis=-1).reshape(
-        *packed.shape[:-1], 2 * packed.shape[-1]).astype(jnp.int8)
-    return width_hint(out, 4)
+    return width_hint(kref.unpack_w4(packed), 4)
+
+
+def slice_int4_cols(packed, start, width: int):
+    """Logical columns [start, start + width) of packed int4 words, still
+    packed: one word range, so the slice must be whole packing groups
+    (`start` a multiple of `width`; it may be traced)."""
+    if width % kref.W4_GROUP:
+        raise ValueError(f"a {width}-column slice of packed int4 words "
+                         f"cuts {kref.W4_GROUP}-column packing groups")
+    return jax.lax.dynamic_slice_in_dim(packed, start // 2, width // 2,
+                                        axis=packed.ndim - 1)

@@ -135,3 +135,15 @@ def test_mul4_block_none_stays_correct(tuner_cache, rng):
                 mul4.mul4_split(a, b)):
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_tune_raises_when_every_native_block_fails(tuner_cache):
+    # a Mosaic kernel cannot run natively on the CPU backend, so every
+    # candidate fails there: tuning raises with the first error instead of
+    # quietly handing back the default block, and records nothing
+    with pytest.raises(RuntimeError, match="every quant_matmul block"):
+        autotune.tune("quant_matmul", 8, 128, 256,
+                      candidates=((128, 128, 256),), iters=1,
+                      lowering="tpu-pallas", interpret=False)
+    assert autotune.lookup("quant_matmul", 8, 128, 256,
+                           lowering="tpu-pallas", interpret=False) is None

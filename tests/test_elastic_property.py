@@ -9,13 +9,16 @@ space instead of hand-picked sites).
 Like tests/test_resilience_property.py, the reference invariant is
 prefix-wise so it is timing-robust; the twin-run invariant (two engines
 armed with IDENTICAL schedules) is exact -- same fired sites, same lost
-devices, same tokens."""
+devices, same tokens.  The engines run on a virtual clock: with real
+compute time on the clock, staggered arrivals would join batches
+according to how long the first run spent compiling its recovery graphs,
+and the twin would dispatch a different site sequence."""
 import jax
 import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro import configs
 from repro.distributed import elastic
@@ -60,10 +63,28 @@ def _injector(loss, faults):
         lose_at_sites=tuple((f"{k}:{i}", n) for k, i, n in loss))
 
 
+class _TickClock(scheduler.Clock):
+    """Virtual time: every reading advances it by one tick and idle waits
+    jump ahead, so the engine's schedule is a pure function of its inputs
+    and never of how long a compile took.  The tick is a power of two, so
+    a reading never lands exactly on a 0.01*i arrival: a zero TTL lapses
+    at arrival, as it does on a wall clock."""
+
+    def __init__(self, tick: float = 2.0 ** -8):
+        self._t, self._tick = 0.0, tick
+
+    def now(self) -> float:
+        self._t += self._tick
+        return self._t
+
+    def wait_until(self, t: float) -> None:
+        self._t = max(self._t, t)
+
+
 def _run(cfg, params, ttls, chaos):
     eng = ServeEngine(params, cfg, n_slots=3, max_cache_len=64,
                       segment_len=4, chaos=chaos)
-    eng.run(_traffic(cfg, ttls), clock=scheduler.FastForwardClock())
+    eng.run(_traffic(cfg, ttls), clock=_TickClock())
     return eng
 
 
@@ -128,6 +149,12 @@ def test_injector_tape_replays_identically(loss, faults, n_sites):
 @pytest.mark.parametrize("fam", sorted(FAMILY_ARCHS))
 @given(loss=_LOSS, faults=_FAULTS, ttls=_TTL_MIXES)
 @settings(max_examples=4, deadline=None)
+# twins that dispatched different sites on a wall-paced clock
+@example(loss=[("verify", 4, 1), ("prefill", 1, 2)],
+         faults=[("prefill", 0), ("prefill", 5)],
+         ttls=[1e6, None, None, 0.0, None])
+@example(loss=[("segment", 0, 4), ("segment", 5, 1)], faults=[],
+         ttls=[1e6, 0.0, None, 0.0, 1e6])
 def test_streams_bit_identical_under_random_loss(setups, fam, loss,
                                                  faults, ttls):
     ttls = tuple(ttls)

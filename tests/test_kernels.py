@@ -7,8 +7,8 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import (mul4, muladd2, packed_matmul, quant_matmul, ref,
-                           simd_add)
+from repro.kernels import (common, mul4, muladd2, packed_matmul,
+                           quant_matmul, ref, simd_add)
 
 shapes_st = st.sampled_from([(5,), (64,), (257,), (8, 33), (3, 5, 7),
                              (1024,), (33, 130)])
@@ -167,7 +167,35 @@ def test_pack_w4_roundtrip(rng):
     w4 = jnp.asarray(rng.integers(-8, 8, (16, 32)), jnp.int8)
     wp = ref.pack_w4(w4)
     assert wp.shape == (16, 16)
+    # 32 columns are one short group: low nibbles hold columns 0..15,
+    # high nibbles columns 16..31
     lo = (wp.astype(jnp.int32) & 0xF) - 8
     hi = wp.astype(jnp.int32) >> 4
-    back = jnp.stack([lo, hi], axis=-1).reshape(16, 32)
+    back = jnp.concatenate([lo, hi], axis=-1)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(w4))
+    np.testing.assert_array_equal(np.asarray(ref.unpack_w4(wp)),
+                                  np.asarray(w4))
+
+
+@pytest.mark.parametrize("n", [2, 62, 256, 320, 512, 2816])
+def test_w4_layout_groups(n, rng):
+    """Whole groups of 256 columns put 128 low-nibble columns before 128
+    high-nibble ones; the unpack and the kernel column fix-up agree with
+    that, tail group included."""
+    w4 = jnp.asarray(rng.integers(-8, 8, (3, 4, n)), jnp.int8)
+    wp = ref.pack_w4(w4)
+    assert wp.shape == (3, 4, n // 2)
+    np.testing.assert_array_equal(np.asarray(ref.unpack_w4(wp)),
+                                  np.asarray(w4))
+    if n >= 256:
+        hi = np.asarray(wp[..., :128]).astype(np.int32) >> 4
+        np.testing.assert_array_equal(hi, np.asarray(w4[..., 128:256]))
+    # a kernel writes every 128-word tile as a whole group
+    lo, hi = ref.w4_nibbles(wp[0])
+    nhp = -(-(n // 2) // 128) * 128
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, nhp - n // 2)))
+    raw = jnp.concatenate(
+        [jnp.concatenate([pad(lo)[:, g:g + 128], pad(hi)[:, g:g + 128]], -1)
+         for g in range(0, nhp, 128)], axis=-1)
+    np.testing.assert_array_equal(np.asarray(ref.w4_kernel_cols(raw, n)),
+                                  np.asarray(w4[0]))

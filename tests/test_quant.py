@@ -7,6 +7,7 @@ import pytest
 from repro import quant
 from repro.quant.qtensor import (QTensor, qmatmul, quantize_tree_for_serving,
                                  quantize_weight)
+from repro.quant.quantize import slice_int4_cols
 
 
 def test_quantize_roundtrip_accuracy(rng):
@@ -105,3 +106,42 @@ def test_width_hint_survives_grad():
 
     g = jax.grad(lambda x: f(x) * 0.0 + (x * x).sum())(jnp.ones((4,)))
     np.testing.assert_allclose(np.asarray(g), 2 * np.ones((4,)))
+
+
+@pytest.mark.parametrize("n,start,width", [(1024, 256, 512), (1024, 512, 256),
+                                           (128, 32, 32), (576, 288, 288)])
+def test_slice_int4_cols(n, start, width, rng):
+    """A column slice of packed int4 words is one word range when it is
+    whole 256-column packing groups; any other slice is refused."""
+    q4 = jnp.asarray(rng.integers(-8, 8, (2, 8, n)), jnp.int8)
+    packed = quant.pack_int4(q4)
+    if width % 256:
+        with pytest.raises(ValueError, match="packing groups"):
+            slice_int4_cols(packed, start, width)
+        return
+    got = slice_int4_cols(packed, start, width)
+    np.testing.assert_array_equal(
+        np.asarray(quant.unpack_int4(got)),
+        np.asarray(q4[..., start:start + width]))
+
+
+@pytest.mark.parametrize("n_kv,size,ok", [(4, 2, True), (4, 4, False),
+                                          (2, 2, False), (2, 1, True)])
+def test_w4_tensor_parallel_needs_whole_groups(n_kv, size, ok):
+    """yi-6b's shape: 32 heads, 4 kv heads of 128.  Over `size` model
+    shards each w4a8 q/k/v slice must be whole packing groups."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import attention
+    cfg = dataclasses.replace(configs.get_config("yi-6b"), n_kv=n_kv)
+    d = 8
+    w4 = lambda n: QTensor(jnp.zeros((d, n // 2), jnp.int8),
+                           jnp.ones((1, n), jnp.float32), "w4a8")
+    params = {"layers": {"attn": {"wq": w4(cfg.q_dim), "wk": w4(cfg.kv_dim),
+                                  "wv": w4(cfg.kv_dim)}}}
+    if ok:
+        attention.check_w4_tp(params, cfg, size)
+    else:
+        with pytest.raises(ValueError, match="'wk'.*packing groups"):
+            attention.check_w4_tp(params, cfg, size)

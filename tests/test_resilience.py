@@ -355,6 +355,8 @@ def test_engine_loop_survives_unexpected_error(family_setup):
         object.__setattr__(eng._bundle, "segment", real)
     rb = eng.cache_info()["robustness"]
     assert rb["errors"] == 1 and rb["faults_injected"] == 0
+    # the real error is kept for diagnosis; recovery hid it otherwise
+    assert eng.cache_info()["first_error"] == "Boom: transient device error"
     _assert_bit_exact(ref, out)
 
 
@@ -439,6 +441,7 @@ def test_robustness_counters_reported(family_setup):
         "replayed_tokens", "replay_divergence", "duplicate_rejects",
         "snapshots", "restores", "drains"}
     assert info["resilience"]["chaos"] is None
+    assert info["first_error"] is None
     assert info["resilience"]["shed_policy"] == "reject-new"
 
 

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro import core as silvia
 from repro.core import opcount
@@ -53,9 +53,14 @@ def build_program(opcodes):
 opcode_st = st.tuples(st.integers(0, 4), st.integers(0, 7),
                       st.integers(0, 7))
 
+# two add tuples that each read a value the other defines:
+# {d = a-a, g = a-e} and {e = a+a, f = d+a} -- packing both is a cycle
+CROSS_TUPLES = [(4, 0, 0), (2, 0, 0), (2, 3, 0), (4, 0, 4)]
+
 
 @settings(max_examples=30, deadline=None)
 @given(st.lists(opcode_st, min_size=2, max_size=12), st.integers(0, 2**31))
+@example(CROSS_TUPLES, 0)
 def test_random_programs_preserve_semantics(opcodes, seed):
     rng = np.random.default_rng(seed)
     fn = build_program(opcodes)
@@ -71,6 +76,7 @@ def test_random_programs_preserve_semantics(opcodes, seed):
 
 @settings(max_examples=20, deadline=None)
 @given(st.lists(opcode_st, min_size=2, max_size=12), st.integers(0, 2**31))
+@example(CROSS_TUPLES, 0)
 def test_density_never_decreases(opcodes, seed):
     rng = np.random.default_rng(seed)
     fn = build_program(opcodes)

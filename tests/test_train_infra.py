@@ -11,6 +11,7 @@ from repro import checkpoint, configs
 from repro.data import DataConfig, make_stream
 from repro.distributed.fault import (Heartbeat,
                                      SimulatedFailure, StragglerDetector)
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          compress_grads, compressed_psum, decompress_grads,
@@ -222,8 +223,7 @@ def test_compression_error_feedback_unbiased():
 
 
 def test_compressed_psum_under_shard_map():
-    mesh = jax.make_mesh((1,), ("data",))
-    from jax.experimental.shard_map import shard_map
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     g = {"w": jnp.asarray([1.0, -2.0, 3.0])}
@@ -232,6 +232,7 @@ def test_compressed_psum_under_shard_map():
         q, s, _ = compress_grads(gl)
         return compressed_psum(q, s, "data")
 
-    out = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(g)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                        check_vma=False)(g)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=0.05)
