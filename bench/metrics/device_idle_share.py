@@ -1,0 +1,10 @@
+"""Device: the share of the traced window in which no operation ran on
+the device (1 - union of device-op intervals / window).  A trace with no
+device operation has nothing to read."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace.window_s <= 0 or \
+            not ctx.trace.n_events:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
