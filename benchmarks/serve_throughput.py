@@ -145,8 +145,10 @@ def run_engine(params, cfg, requests, *, n_slots, max_cache_len,
     elapsed = end - t0
     info = eng.cache_info()
     out = _summary(eng.finished, elapsed)
-    out["mean_occupancy"] = round(float(np.mean(eng.occupancy)), 3) \
-        if eng.occupancy else 0.0
+    occ = info["occupancy"]
+    out["mean_occupancy"] = round(
+        occ["active_slot_segments"] / occ["slot_segments"], 3) \
+        if occ["slot_segments"] else 0.0
     ttfts = [r.first_token_time - r.arrival_time for r in eng.finished
              if r.first_token_time is not None]
     out["ttft_p50_ms"] = round(float(np.percentile(ttfts, 50)) * 1e3, 2) \

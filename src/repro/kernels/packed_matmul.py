@@ -76,6 +76,7 @@ def packed_w4_matmul_acc(x_q, w_packed, *, block=None,
                   pl.BlockSpec((bk, bnh), lambda i, j, kk: (kk, j))],
         out_specs=pl.BlockSpec((bm, 2 * bnh), lambda i, j, kk: (i, j)),
         interpret=interpret,
+        name="packed_w4_matmul",
     )(x_p, w_p)
     return w4_kernel_cols(out[:m], n)
 

@@ -57,6 +57,7 @@ def quant_matmul_acc(x_q, w_q, *, block=None,
                   pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         interpret=interpret,
+        name="quant_matmul",
     )(x_p, w_p)
     return out[:m, :n]
 

@@ -119,6 +119,7 @@ from repro.launch import resilience as res
 from repro.launch import sampling
 from repro.launch import scheduler
 from repro.launch import serve
+from repro.launch import telemetry
 from repro.models import attention, lm
 from repro.models import slot_state
 
@@ -623,6 +624,7 @@ class _PendingSegment:
     tok: object     # [bb, 1] final tokens
     pos: object     # [bb] final positions
     bad: object     # [bb] non-finite quarantine flags
+    span: telemetry.Open    # engine.segment, ended by _finish_segment
 
 
 class ServeEngine:
@@ -839,7 +841,9 @@ class ServeEngine:
         self.finished: List[scheduler.Request] = []
         self.total_generated = 0
         self.compactions = 0
-        self.occupancy: List[float] = []
+        # decode occupancy: slots active over slot pages, summed over the
+        # segments (and speculative rounds) dispatched
+        self._occupancy = {"active_slot_segments": 0, "slot_segments": 0}
         self._graphs: set = set()
         # -- resilience state (launch/resilience.py) --
         self._res = resilience if resilience is not None \
@@ -890,13 +894,17 @@ class ServeEngine:
 
     # -- request lifecycle --------------------------------------------------
 
-    def submit(self, req: scheduler.Request) -> str:
+    def submit(self, req: scheduler.Request,
+               submitted: Optional[float] = None) -> str:
         """Validate and enqueue; returns resilience.QUEUED, or
         resilience.SHED when the bounded queue rejects the newcomer under
         the reject-new policy (under drop-oldest the VICTIM is shed and
         the newcomer queued).  Malformed requests and duplicate rids still
         raise -- those are caller bugs, not load conditions (duplicates
-        would corrupt per-rid results and recovery bookkeeping)."""
+        would corrupt per-rid results and recovery bookkeeping).
+        `submitted` is the `telemetry.now()` stamp of the client's own
+        submit, where a front end took it first: the request's
+        `request.queued` span starts there (else now)."""
         if req.rid in self._rids:
             self._robust["duplicate_rejects"] += 1
             raise ValueError(
@@ -939,6 +947,7 @@ class ServeEngine:
                              f"drop-oldest")
         self._rids.add(req.rid)
         self._queue.submit(req)
+        telemetry.request_begin("request.queued", req, submitted)
         return res.QUEUED
 
     def _finish(self, req: scheduler.Request, now: float,
@@ -947,6 +956,7 @@ class ServeEngine:
         req.finish_time = now
         req.outcome = outcome
         req.error = error
+        telemetry.request_done(req)
         if outcome == res.FAILED:
             self._robust["failed"] += 1
         self.finished.append(req)
@@ -995,11 +1005,12 @@ class ServeEngine:
         holes = np.asarray([i for i in range(self.n_slots)
                             if not self._active[i]], np.int64)
         perm = np.concatenate([live, holes])
-        self._cache = self._spec.permute_slots(self._cache, perm)
-        self._samp = sampling.permute(self._samp, perm)
-        if self._sd is not None:
-            self._draft_cache = self._draft_spec.permute_slots(
-                self._draft_cache, perm)
+        with telemetry.span("engine.compact"):
+            self._cache = self._spec.permute_slots(self._cache, perm)
+            self._samp = sampling.permute(self._samp, perm)
+            if self._sd is not None:
+                self._draft_cache = self._draft_spec.permute_slots(
+                    self._draft_cache, perm)
         self._tok = self._tok[perm]
         self._pos = self._pos[perm]
         self._active = self._active[perm]
@@ -1013,6 +1024,24 @@ class ServeEngine:
 
     def _admit(self, now: float, clock: scheduler.Clock,
                resume_only: bool = False) -> int:
+        with telemetry.span("engine.admit") as sp:
+            n = self._admit_ready(now, clock, resume_only)
+            sp.count(requests=n)
+            if not n:
+                sp.drop()
+        return n
+
+    @staticmethod
+    def _note_popped(reqs: List[scheduler.Request]) -> None:
+        """The requests left the queue: their queue wait ends and their
+        prefill wait begins, at one stamp."""
+        t = telemetry.now()
+        for r in reqs:
+            telemetry.request_end("request.queued", r, t)
+            telemetry.request_begin("request.prefill", r, t)
+
+    def _admit_ready(self, now: float, clock: scheduler.Clock,
+                     resume_only: bool) -> int:
         self._compact()
         # resume_only (drain): only requests a fault recovery requeued --
         # carrying emitted tokens (generate) or a retry count (score/embed
@@ -1029,6 +1058,7 @@ class ServeEngine:
             now, limit=self.n_slots,
             predicate=lambda r: r.method == "embed"
             and (pred is None or pred(r)))
+        self._note_popped(embeds)
         n_embed = self._admit_embed(embeds, clock) if embeds else 0
         free = [i for i in range(self.n_slots) if not self._active[i]]
         ready = self._queue.pop_ready(
@@ -1039,6 +1069,7 @@ class ServeEngine:
             ready = self._defer_over_budget(ready)
         if not ready:
             return n_embed
+        self._note_popped(ready)
         # popped but not yet registered in a slot: a fault mid-admission
         # leaves the leftovers here for _recover to requeue
         self._admitting = list(ready)
@@ -1168,23 +1199,26 @@ class ServeEngine:
         if self._prefix is None:
             bb = scheduler.bucket_pow2(g, minimum=self._adm_floor,
                                        maximum=self.n_slots)
-            inputs, lens = self._prefill_inputs(group, bb, sb, eb)
-            if self.prefill_chunk is None:
-                self._graphs.add(("prefill", bb, sb, t_pre)
-                                 + (() if eb is None else (eb,)))
-                tok0, last, rows, bad0 = self._guarded(
-                    "prefill", self._bundle.prefill, self.params, inputs,
-                    jnp.asarray(lens - 1), t_pre, self.enc_len)
-            else:
-                tok0, last, rows, bad0 = self._chunked_prefill(
-                    np.asarray(inputs), lens, t_pre)
-            tok0 = np.asarray(tok0)
-            bad0 = np.asarray(bad0)
+            with telemetry.span("engine.prefill", rows=bb * sb,
+                                tokens=sum(r.prompt_len for r in group)):
+                inputs, lens = self._prefill_inputs(group, bb, sb, eb)
+                if self.prefill_chunk is None:
+                    self._graphs.add(("prefill", bb, sb, t_pre)
+                                     + (() if eb is None else (eb,)))
+                    tok0, last, rows, bad0 = self._guarded(
+                        "prefill", self._bundle.prefill, self.params,
+                        inputs, jnp.asarray(lens - 1), t_pre, self.enc_len)
+                else:
+                    tok0, last, rows, bad0 = self._chunked_prefill(
+                        np.asarray(inputs), lens, t_pre)
+                tok0 = np.asarray(tok0)
+                bad0 = np.asarray(bad0)
             slots = np.asarray([free.pop(0) for _ in range(g)], np.int32)
             # scatter the admitted pages into their slots; leaves without
             # a length axis (SSM/conv state, cross-KV) are reset wholesale
-            self._cache = self._spec.admit(self._cache, rows, slots, g,
-                                           t_pre=t_pre)
+            with telemetry.span("engine.scatter", group=g):
+                self._cache = self._spec.admit(self._cache, rows, slots, g,
+                                               t_pre=t_pre)
             if self._sd is not None:
                 # draft prefill: same prompts, same bucket, same slots --
                 # draft and target stay position-synchronized (they share
@@ -1224,7 +1258,9 @@ class ServeEngine:
         # writable copy: np.asarray over a device array is read-only, and
         # sampled admissions override their row's tok0 below
         tok0 = np.array(tok0)
+        t = telemetry.now()
         for i, r in enumerate(group):
+            telemetry.request_end("request.prefill", r, t)
             slot = int(slots[i])
             self._admitting = [x for x in self._admitting if x is not r]
             self._method_admits[r.method] += 1
@@ -1387,21 +1423,25 @@ class ServeEngine:
             sub = [group[i] for i in miss_idx]
             bb = scheduler.bucket_pow2(len(sub), minimum=self._adm_floor,
                                        maximum=self.n_slots)
-            inputs, lens = self._prefill_inputs(sub, bb, sb, eb)
-            self._graphs.add(("prefill", bb, sb, t_pre)
-                             + (() if eb is None else (eb,)))
-            stok0, slast, rows, sbad0 = self._guarded(
-                "prefill", self._bundle.prefill, self.params, inputs,
-                jnp.asarray(lens - 1), t_pre, self.enc_len)
-            stok0 = np.asarray(stok0)
-            sbad0 = np.asarray(sbad0)
+            with telemetry.span("engine.prefill", rows=bb * sb,
+                                tokens=sum(r.prompt_len for r in sub)):
+                inputs, lens = self._prefill_inputs(sub, bb, sb, eb)
+                self._graphs.add(("prefill", bb, sb, t_pre)
+                                 + (() if eb is None else (eb,)))
+                stok0, slast, rows, sbad0 = self._guarded(
+                    "prefill", self._bundle.prefill, self.params, inputs,
+                    jnp.asarray(lens - 1), t_pre, self.enc_len)
+                stok0 = np.asarray(stok0)
+                sbad0 = np.asarray(sbad0)
             need_last = any(group[i].method == "score"
                             or not sampling.is_greedy(group[i])
                             for i in miss_idx)
             slast_np = np.asarray(slast) if need_last else None
             sub_slots = slots[np.asarray(miss_idx, np.int64)]
-            self._cache = self._spec.admit(self._cache, rows, sub_slots,
-                                           len(sub), t_pre=t_pre)
+            with telemetry.span("engine.scatter", group=len(sub)):
+                self._cache = self._spec.admit(self._cache, rows,
+                                               sub_slots, len(sub),
+                                               t_pre=t_pre)
             for j, i in enumerate(miss_idx):
                 tok0[i, 0] = stok0[j, 0]
                 bad0[i] = sbad0[j]
@@ -1477,37 +1517,45 @@ class ServeEngine:
             resume[i] = min(len(hit.chain), int(last_chunk[i]))
             self._prefix.note_skip(int(resume[i]) * c)
         last: Dict[int, object] = {}
-        for k in range(n_chunks):
-            act = (resume <= k) & (k <= last_chunk)
-            act[g:] = False
-            if not act.any():
-                continue    # every row is past this chunk: no dispatch
-            self._graphs.add(("chunk", bb, c, t_pre))
-            toks = jnp.asarray(prompts[:, k * c:(k + 1) * c])
-            pos = jnp.full((bb,), k * c, jnp.int32)
-            logits, cache = self._guarded(
-                "chunk", self._bundle.chunk_step, self.params, toks,
-                cache, pos, jnp.asarray(act))
-            hit_rows = np.nonzero((last_chunk == k) & act)[0]
-            if hit_rows.size:
-                # harvest on the host: a device gather would compile one
-                # program per hit-row arity, and argmax over the exact
-                # same bits is order-free either way
-                lg = np.asarray(logits)
-                for b in hit_rows:
-                    last[int(b)] = lg[int(b), int((lens[b] - 1) % c)]
-        tok0 = np.zeros((g, 1), np.int32)
-        bad0 = np.zeros((g,), bool)
-        for i in range(g):
-            if term[i] is not None:
-                tok0[i, 0] = term[i].tok0
-                continue
-            row = np.asarray(last[i])
-            # host argmax over identical logits bits == the device argmax
-            # (comparison-based, no float accumulation; same argument as
-            # _replay_step)
-            tok0[i, 0] = int(np.argmax(row))
-            bad0[i] = not bool(np.all(np.isfinite(row)))
+        with telemetry.span("engine.prefill", tokens=sum(
+                int(lens[i]) - int(resume[i]) * c
+                for i in range(g) if term[i] is None)) as sp:
+            n_run = 0
+            for k in range(n_chunks):
+                act = (resume <= k) & (k <= last_chunk)
+                act[g:] = False
+                if not act.any():
+                    continue    # every row is past this chunk: no dispatch
+                n_run += 1
+                self._graphs.add(("chunk", bb, c, t_pre))
+                toks = jnp.asarray(prompts[:, k * c:(k + 1) * c])
+                pos = jnp.full((bb,), k * c, jnp.int32)
+                logits, cache = self._guarded(
+                    "chunk", self._bundle.chunk_step, self.params, toks,
+                    cache, pos, jnp.asarray(act))
+                hit_rows = np.nonzero((last_chunk == k) & act)[0]
+                if hit_rows.size:
+                    # harvest on the host: a device gather would compile
+                    # one program per hit-row arity, and argmax over the
+                    # exact same bits is order-free either way
+                    lg = np.asarray(logits)
+                    for b in hit_rows:
+                        last[int(b)] = lg[int(b), int((lens[b] - 1) % c)]
+            tok0 = np.zeros((g, 1), np.int32)
+            bad0 = np.zeros((g,), bool)
+            for i in range(g):
+                if term[i] is not None:
+                    tok0[i, 0] = term[i].tok0
+                    continue
+                row = np.asarray(last[i])
+                # host argmax over identical logits bits == the device
+                # argmax (comparison-based, no float accumulation; same
+                # argument as _replay_step)
+                tok0[i, 0] = int(np.argmax(row))
+                bad0[i] = not bool(np.all(np.isfinite(row)))
+            sp.count(rows=n_run * bb * c)
+            if not n_run:
+                sp.drop()
         # donate computed pages back to the pool (never from a faulted
         # dispatch -- an exception above unwinds before this point)
         for i in range(g):
@@ -1528,8 +1576,9 @@ class ServeEngine:
             self._prefix.insert_terminal(r, full, int(tok0[i, 0]))
         pins = [self._prefix.pin(pk) for pk in pin_keys]
         slots = np.asarray([free.pop(0) for _ in range(g)], np.int32)
-        self._cache = self._spec.admit(self._cache, cache, slots, g,
-                                       t_pre=t_pre)
+        with telemetry.span("engine.scatter", group=g):
+            self._cache = self._spec.admit(self._cache, cache, slots, g,
+                                           t_pre=t_pre)
         self._reshard_state()
         return tok0, bad0, slots, pins, last
 
@@ -1590,22 +1639,34 @@ class ServeEngine:
         bb, t_b = self._segment_shape()
         n_steps = self.segment_len
         self._graphs.add(("segment", bb, t_b, n_steps))
-        fast = bb == self.n_slots and (t_b is None
-                                       or t_b == self.max_cache_len)
-        cache_in = self._cache if fast else \
-            self._spec.slice_live(self._cache, bb, t_b)
-        seq, tok, cache_out, pos, bad = self._guarded(
-            "segment", self._bundle.segment,
-            self.params, jnp.asarray(self._tok[:bb]), cache_in,
-            jnp.asarray(self._pos[:bb]), jnp.asarray(self._active[:bb]),
-            sampling.operand(self._samp, bb), n_steps)
-        if fast:
-            self._cache = cache_out
-        else:
-            self._cache = self._spec.merge_live(self._cache, cache_out,
-                                                bb, t_b)
-        self.occupancy.append(float(np.sum(self._active)) / self.n_slots)
-        return _PendingSegment(bb=bb, seq=seq, tok=tok, pos=pos, bad=bad)
+        active = int(np.sum(self._active))
+        sp = telemetry.span("engine.segment", active=active, bb=bb,
+                            t_b=t_b or 0)
+        try:
+            fast = bb == self.n_slots and (t_b is None
+                                           or t_b == self.max_cache_len)
+            cache_in = self._cache if fast else \
+                self._spec.slice_live(self._cache, bb, t_b)
+            seq, tok, cache_out, pos, bad = self._guarded(
+                "segment", self._bundle.segment,
+                self.params, jnp.asarray(self._tok[:bb]), cache_in,
+                jnp.asarray(self._pos[:bb]), jnp.asarray(self._active[:bb]),
+                sampling.operand(self._samp, bb), n_steps)
+            if fast:
+                self._cache = cache_out
+            else:
+                self._cache = self._spec.merge_live(self._cache, cache_out,
+                                                    bb, t_b)
+        except BaseException:
+            sp.close()
+            raise
+        self._note_occupancy(active)
+        return _PendingSegment(bb=bb, seq=seq, tok=tok, pos=pos, bad=bad,
+                               span=sp)
+
+    def _note_occupancy(self, active: int) -> None:
+        self._occupancy["active_slot_segments"] += active
+        self._occupancy["slot_segments"] += self.n_slots
 
     def _finish_segment(self, p: "_PendingSegment",
                         clock: scheduler.Clock) -> None:
@@ -1615,12 +1676,22 @@ class ServeEngine:
         inactive slot's tok/pos are dead state -- admission overwrites
         them before the slot decodes again, and _harvest skips slots
         whose request is gone."""
-        self._tok[:p.bb] = np.asarray(p.tok)
-        self._pos[:p.bb] = np.asarray(p.pos)
-        self._harvest(np.asarray(p.seq), np.asarray(p.bad), clock.now())
+        with p.span:
+            with telemetry.span("engine.sync"):
+                self._tok[:p.bb] = np.asarray(p.tok)
+                self._pos[:p.bb] = np.asarray(p.pos)
+                seq, bad = np.asarray(p.seq), np.asarray(p.bad)
+            self._harvest(seq, bad, clock.now())
 
     def _harvest(self, seq: np.ndarray, bad: np.ndarray,
                  now: float) -> None:
+        with telemetry.span("engine.harvest") as sp:
+            before = self.total_generated
+            self._harvest_slots(seq, bad, now)
+            sp.count(tokens=self.total_generated - before)
+
+    def _harvest_slots(self, seq: np.ndarray, bad: np.ndarray,
+                       now: float) -> None:
         n_steps, bb = seq.shape
         for slot in range(bb):
             req = self._slot_req[slot]
@@ -1672,6 +1743,10 @@ class ServeEngine:
         models sample under the SAME per-slot counter keys, so acceptance
         is a pure function of (seed, rid, token prefix) -- recovery
         replay is therefore acceptance-history-exact by construction."""
+        with telemetry.span("engine.spec_round"):
+            self._spec_round_inner(clock)
+
+    def _spec_round_inner(self, clock: scheduler.Clock) -> None:
         k = self._sd.k
         hi = int(np.max(np.nonzero(self._active)[0])) + 1
         bb = scheduler.bucket_pow2(hi, minimum=self.min_batch_bucket,
@@ -1718,7 +1793,7 @@ class ServeEngine:
                                                 bb, t_b)
             self._draft_cache = self._draft_spec.merge_live(
                 self._draft_cache, d_cache, bb, t_b)
-        self.occupancy.append(float(np.sum(self._active)) / self.n_slots)
+        self._note_occupancy(int(np.sum(self._active)))
         self._pos[:bb] = np.asarray(pos_out)
         self._spec_harvest(np.asarray(g_seq), np.asarray(m),
                            np.asarray(bad), clock.now())
@@ -2573,6 +2648,7 @@ class ServeEngine:
             "len_buckets": list(self.len_buckets),
             "enc_buckets": list(self.enc_buckets),
             "compactions": self.compactions,
+            "occupancy": dict(self._occupancy),
             "methods": {"admits": dict(self._method_admits)},
             "lowerings": dict(self._lowerings),
             "decode_bundle_lru": serve.decode_cache_info(),

@@ -39,7 +39,6 @@ import dataclasses
 import itertools
 import queue as _thread_queue
 import threading
-import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -47,6 +46,7 @@ import numpy as np
 from repro.launch import methods
 from repro.launch import resilience as res
 from repro.launch import scheduler
+from repro.launch import telemetry
 
 
 @dataclasses.dataclass
@@ -95,8 +95,8 @@ class AsyncFrontend:
         self._streams: dict = {}       # rid -> asyncio.Queue
         self._waiters: dict = {}       # rid -> asyncio.Future
         self.stats = {"submitted": 0, "streamed_tokens": 0,
-                      "overlapped_segments": 0, "disconnect_cancels": 0,
-                      "backlog_cancels": 0, "hidden_host_s": 0.0}
+                      "disconnect_cancels": 0, "backlog_cancels": 0,
+                      "hidden_host_s": 0.0}
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -134,7 +134,7 @@ class AsyncFrontend:
         fut = asyncio.get_running_loop().create_future()
         self._waiters[req.rid] = fut
         self.stats["submitted"] += 1
-        self._cmds.put(("submit", req))
+        self._cmds.put(("submit", req, telemetry.now()))
         try:
             return await fut
         finally:
@@ -171,7 +171,7 @@ class AsyncFrontend:
         req = methods.generate_request(
             rid, prompt, max_new_tokens, arrival_time=self.clock.now(),
             stop_tokens=stop_tokens, features=features, deadline=deadline)
-        self._cmds.put(("submit", req))
+        self._cmds.put(("submit", req, telemetry.now()))
         done = False
         try:
             while True:
@@ -226,10 +226,9 @@ class AsyncFrontend:
                     # dispatched segment is in flight.  hidden_host_s is
                     # the measured overlap -- host time that a sync loop
                     # would have added to the dispatch-to-dispatch path.
-                    self.stats["overlapped_segments"] += 1
-                    t0 = time.monotonic()
-                    self._host_stage()
-                    self.stats["hidden_host_s"] += time.monotonic() - t0
+                    with telemetry.span("frontend.host_stage") as sp:
+                        self._host_stage()
+                    self.stats["hidden_host_s"] += sp.end - sp.start
                     eng.step_finish(pending, clock)
                     self._publish()
                 else:
@@ -255,6 +254,10 @@ class AsyncFrontend:
         """Nothing active and nothing admitted: wait for the next queued
         arrival (virtual clocks jump straight to it) or the next client
         command, whichever is first."""
+        with telemetry.span("frontend.idle_wait"):
+            self._wait_for_work()
+
+    def _wait_for_work(self) -> None:
         clock = self.clock
         nxt = self.engine.next_arrival(clock.now())
         if isinstance(clock, scheduler.FastForwardClock):
@@ -283,10 +286,10 @@ class AsyncFrontend:
         if cmd[0] == "stop":
             self._stop_flag = True
         elif cmd[0] == "submit":
-            req = cmd[1]
+            _, req, submitted = cmd
             self._live[req.rid] = req
             try:
-                self.engine.submit(req)
+                self.engine.submit(req, submitted=submitted)
             except Exception as e:  # validation error -> the caller
                 self._live.pop(req.rid, None)
                 self._deliver_error(req.rid, e)
@@ -302,6 +305,12 @@ class AsyncFrontend:
         replays never re-append, so a delta is never re-published), and
         completion is detected from the engine's finished list -- both
         plain host reads, safe to run under an in-flight segment."""
+        with telemetry.span("frontend.publish") as sp:
+            before = self.stats["streamed_tokens"]
+            self._publish_deltas()
+            sp.count(tokens=self.stats["streamed_tokens"] - before)
+
+    def _publish_deltas(self) -> None:
         for rid, req in list(self._live.items()):
             if rid in self._streams:
                 sent = self._sent.get(rid, 0)

@@ -176,7 +176,8 @@ def test_slot_admission_eviction_invariants(setup):
         assert len(r.tokens) == r.max_new_tokens
         assert r.finish_time is not None and r.first_token_time is not None
     # slots were reused: 5 requests through 2 slots
-    assert max(eng.occupancy) <= 1.0
+    occ = eng.cache_info()["occupancy"]
+    assert 0 < occ["active_slot_segments"] <= occ["slot_segments"]
 
 
 def test_engine_rejects_oversized_and_unregistered_family(setup):
