@@ -694,6 +694,12 @@ class ServeEngine:
         init_kwargs = {"s_enc": enc_len} if enc_len is not None else {}
         # raises with registry guidance for unregistered families
         self._spec = slot_state.spec_for(cfg, **init_kwargs)
+        # how lm.decode_step writes the stacked state back each step:
+        # leaves with a length axis take their new rows in place, the
+        # constant-size ones are written a whole layer at a time
+        n_row = sum(a is not None for a in self._spec.length_axes)
+        self._state_writes = {"row": n_row,
+                              "layer": len(self._spec.length_axes) - n_row}
         if segment_len < 1:
             raise ValueError("segment_len must be >= 1")
         if prefill_chunk is not None and not self._spec.prefill_chunkable:
@@ -2640,6 +2646,7 @@ class ServeEngine:
         info = {
             "family": self.cfg.family,
             "has_length_axis": self._spec.has_length_axis,
+            "decode_state_writes": dict(self._state_writes),
             "graphs": len(self._graphs),
             "graph_bound": self.graph_bound(),
             "graph_keys": sorted(self._graphs,

@@ -7,7 +7,8 @@ Modes:
   cross(x, memory)             -- encoder-decoder cross attention (whisper)
 
 The KV cache is a dict {k: [B, S_max, KV, D], v: ..., } with positions filled
-up to `pos`; decode updates in place via dynamic_update_slice (functional).
+up to `pos`; decode writes each row's new entries in place into the stacked
+cache through the page handles lm.decode_step passes (lm.StackedPage).
 """
 from __future__ import annotations
 
@@ -170,20 +171,6 @@ def _kv_dequant(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def _cache_insert(cache_t, scale_t, new, pos, quantized: bool):
-    """Insert [B,1,KV,D] `new` at per-row positions into the cache."""
-    if quantized:
-        q, s = _kv_quantize(new)
-        t = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-            c, u, (i, 0, 0)))(cache_t, q, pos)
-        sc = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-            c, u, (i, 0)))(scale_t, s, pos)
-        return t, sc
-    t = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-        c, u, (i, 0, 0)))(cache_t, new, pos)
-    return t, None
-
-
 def _attn_chunked(q, k, v, srcpos, cfg: ModelConfig, q_chunk: int):
     """Causal attention with the query dim scanned in chunks: only a
     [B, KV, G, q_chunk, T] score block is ever live (flash-attention memory
@@ -265,18 +252,17 @@ def attn_full(p, x, cfg: ModelConfig, positions=None, causal: bool = True,
     return out, {"k": kp, "v": vp}
 
 
-def _mask_inactive(new, old, active):
-    """Keep `old` rows wherever active is False (slot not serving a
-    request): inactive slots must not mutate their KV pages."""
-    m = active.reshape((active.shape[0],) + (1,) * (new.ndim - 1))
-    return jnp.where(m, new, old)
-
-
 def attn_decode(p, x_t, cache, pos, cfg: ModelConfig, active=None):
     """Decode C new tokens against the cache: x_t [B, C, d] (C=1 is the
     classic single-token step; C>1 is a chunked-prefill step); pos [B] int32
     position of the FIRST new token per row; active: optional [B] bool slot
     mask -- inactive rows leave their cache untouched.
+
+    `cache` maps each page name (k, v; k_s, v_s under int8 KV) to a page
+    handle (lm.StackedPage): `read()` gives the layer's page,
+    `rows_at(pos, c)` each row's c entries from pos, and `put(rows, pos)`
+    a handle with them written.  Only the new entries are written: an
+    inactive row writes back the entries it already holds there.
 
     Returns (out [B,C,d], new_cache).  Token c of row b is written at cache
     position pos[b]+c and attends causally to positions <= pos[b]+c."""
@@ -291,25 +277,26 @@ def attn_decode(p, x_t, cache, pos, cfg: ModelConfig, active=None):
     if not cfg.learned_pos:
         q = common.apply_rope(q, posq, cfg.rope_theta, cfg.m_rope_sections)
         k_t = common.apply_rope(k_t, posq, cfg.rope_theta, cfg.m_rope_sections)
-    # insert the C new rows at per-row positions pos..pos+C-1
     quantized = cfg.serve_kv_dtype == "int8"
-    kc, ksc = _cache_insert(cache["k"], cache.get("k_s"), k_t, pos,
-                            quantized)
-    vc, vsc = _cache_insert(cache["v"], cache.get("v_s"), v_t, pos,
-                            quantized)
-    if active is not None:
-        kc = _mask_inactive(kc, cache["k"], active)
-        vc = _mask_inactive(vc, cache["v"], active)
-        if quantized:
-            ksc = _mask_inactive(ksc, cache["k_s"], active)
-            vsc = _mask_inactive(vsc, cache["v_s"], active)
     if quantized:
-        k = _kv_dequant(kc, ksc, x_t.dtype)
-        v = _kv_dequant(vc, vsc, x_t.dtype)
-        new_cache = {"k": kc, "v": vc, "k_s": ksc, "v_s": vsc}
+        kq, ks = _kv_quantize(k_t)
+        vq, vs = _kv_quantize(v_t)
+        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
     else:
-        k, v = kc, vc
-        new_cache = {"k": kc, "v": vc}
+        rows = {"k": k_t, "v": v_t}
+    if active is not None:
+        rows = {n: jnp.where(active.reshape((b,) + (1,) * (r.ndim - 1)), r,
+                             cache[n].rows_at(pos, c))
+                for n, r in rows.items()}
+    # the C new rows go in at pos..pos+C-1, then the layer is read back
+    new_cache = {n: cache[n].put(r, pos) for n, r in rows.items()}
+    if quantized:
+        k = _kv_dequant(new_cache["k"].read(), new_cache["k_s"].read(),
+                        x_t.dtype)
+        v = _kv_dequant(new_cache["v"].read(), new_cache["v_s"].read(),
+                        x_t.dtype)
+    else:
+        k, v = new_cache["k"].read(), new_cache["v"].read()
     scale = 1.0 / np.sqrt(cfg.head_dim)
     scores = _gqa_scores(q, k, cfg) * scale      # [B,KV,G,C,T]
     t = k.shape[1]

@@ -7,12 +7,13 @@ All families share the skeleton:
 with layer params stacked on a leading axis and the stack run under
 jax.lax.scan (optionally remat'd), so jaxpr/HLO size is depth-independent.
 
-Caches are pytrees stacked over the scan axis; decode threads them through
-the same scan.  Whisper (encdec) runs two scans and carries cross-attention
-KV in the cache.
+Caches are pytrees stacked over the scan axis; decode carries them through
+the same scan and writes each layer's new entries in place.  Whisper
+(encdec) runs two scans and carries cross-attention KV in the cache.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -201,6 +202,74 @@ def prefill(params, inputs, cfg: ModelConfig, cache_len: int,
     return _lm_head(params, x_last, cfg), caches
 
 
+def cache_spec(cfg: ModelConfig, cache) -> slot_state.SlotStateSpec:
+    """The probed slot-state spec of a stacked cache built like
+    init_cache's: per leaf, its slot axis and its length axis (None for
+    constant-size pages).  encdec's cross pages are as wide as the encoder
+    memory, not the cache, so the probe is told that width."""
+    kw = {}
+    if cfg.family == "encdec":
+        kw["s_enc"] = cache["cross"]["k"].shape[2]
+    return slot_state.spec_for(cfg, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class StackedPage:
+    """One leaf of the stacked cache that has a length axis (an attention
+    page or its int8 scales), as one layer of the decode scan sees it.
+
+    `read()` is the layer's page; `rows_at(pos, c)` and `put(rows, pos)`
+    read and write each slot's c entries from its own position, in place
+    in the stack: a gather and a scatter of c-entry windows in CLIP mode,
+    so a start past T-c is clamped exactly as dynamic_update_slice clamps
+    it (the engine's stale-but-masked overrun rows rely on that;
+    launch/engine.py)."""
+
+    stack: Any
+    layer: Any                  # traced layer index
+    batch_axis: int
+    length_axis: int
+
+    def read(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.layer, 0,
+                                            keepdims=False)
+
+    def _windows(self, pos):
+        """Start indices [B, 3] (layer, slot, position) of each slot's
+        window, and the window's axes as gather/scatter numbers."""
+        ba, la, nd = self.batch_axis, self.length_axis, self.stack.ndim
+        n_b = self.stack.shape[ba]
+        idx = jnp.stack([jnp.full((n_b,), self.layer, jnp.int32),
+                         jnp.arange(n_b, dtype=jnp.int32),
+                         pos.astype(jnp.int32)], axis=1)
+        return idx, tuple(range(1, nd - 1)), (0, ba), (0, ba, la)
+
+    def rows_at(self, pos, c: int):
+        idx, window, inserted, to_operand = self._windows(pos)
+        sizes = list(self.stack.shape)
+        sizes[0] = sizes[self.batch_axis] = 1
+        sizes[self.length_axis] = c
+        rows = jax.lax.gather(
+            self.stack, idx,
+            jax.lax.GatherDimensionNumbers(
+                offset_dims=window, collapsed_slice_dims=inserted,
+                start_index_map=to_operand),
+            tuple(sizes), indices_are_sorted=True,
+            mode=jax.lax.GatherScatterMode.CLIP)
+        return jnp.moveaxis(rows, 0, self.batch_axis - 1)
+
+    def put(self, rows, pos) -> "StackedPage":
+        idx, window, inserted, to_operand = self._windows(pos)
+        stack = jax.lax.scatter(
+            self.stack, idx, jnp.moveaxis(rows, self.batch_axis - 1, 0),
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=window, inserted_window_dims=inserted,
+                scatter_dims_to_operand_dims=to_operand),
+            indices_are_sorted=True, unique_indices=True,
+            mode=jax.lax.GatherScatterMode.CLIP)
+        return dataclasses.replace(self, stack=stack)
+
+
 def decode_step(params, token_t, cache, pos, cfg: ModelConfig, active=None):
     """token_t: [B,C] int (or [B,C,d] stub embed); pos: [B] int32 position
     of the first new token per row; active: optional [B] bool slot mask --
@@ -209,29 +278,56 @@ def decode_step(params, token_t, cache, pos, cfg: ModelConfig, active=None):
     chunked-prefill step over the same cache layout.
 
     Returns (logits [B,C,V], new_cache).  Every family has a masked state
-    update (attention: masked KV insert; SSM: masked {ssm, conv} state;
+    update (attention: masked KV rows; SSM: masked {ssm, conv} state;
     encdec: masked self-KV, read-only cross-KV), so inactive slots are
     bit-identical across the step for any registered family
-    (models/slot_state.py; property-tested in tests/test_slot_state.py)."""
+    (models/slot_state.py; property-tested in tests/test_slot_state.py).
+
+    The stacked cache is the layer scan's carry, not its xs/ys: each layer
+    reads its slice at its index and writes back only what it changed.
+    Leaves with a length axis (attention pages, int8 scales) take their C
+    new entries per slot in place; constant-size leaves (SSM state, conv
+    windows, cross-KV) are written whole at the layer's index.  So a step
+    moves the new rows, not a fresh copy of the whole cache."""
     if cfg.family == "encdec":
-        return encdec_decode_step(params, token_t, cache, pos, cfg,
-                                  active=active)
-    x = _embed(params, token_t, cfg)
-    if cfg.learned_pos:
-        qpos = pos[:, None] + jnp.arange(x.shape[1], dtype=pos.dtype)
-        x = x + jnp.take(params["pos_embed"], qpos, axis=0)
-    _, block_fn = BLOCK_FNS[cfg.family]
+        x = jnp.take(params["embed"], token_t, axis=0)
+        x = x + jnp.take(params["pos_embed"], pos, axis=0)[:, None, :]
+        stacked, block_fn = params["dec"], blocks.dec_block
+    else:
+        x = _embed(params, token_t, cfg)
+        if cfg.learned_pos:
+            qpos = pos[:, None] + jnp.arange(x.shape[1], dtype=pos.dtype)
+            x = x + jnp.take(params["pos_embed"], qpos, axis=0)
+        stacked, block_fn = params["blocks"], BLOCK_FNS[cfg.family][1]
+    spec = cache_spec(cfg, cache)
+    treedef = jax.tree_util.tree_structure(cache)
+    if treedef != spec.treedef:
+        raise ValueError(f"cache tree {treedef} is not the {cfg.family!r} "
+                         f"cache layout {spec.treedef}")
+    n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
 
-    def body(h, xs):
-        layer_params, layer_cache = xs
-        h2, new_cache, _ = block_fn(layer_params, h, cfg, mode="decode",
-                                    cache=layer_cache, pos=pos,
-                                    active=active)
-        return h2, new_cache
+    def body(carry, xs):
+        h, stack = carry
+        layer_params, layer = xs
+        leaves = jax.tree_util.tree_leaves(stack)
+        view = [jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+                if la is None else StackedPage(t, layer, ba, la)
+                for t, ba, la in zip(leaves, spec.batch_axes,
+                                     spec.length_axes)]
+        h, upd, _ = block_fn(layer_params, h, cfg, mode="decode",
+                             cache=jax.tree_util.tree_unflatten(treedef,
+                                                                view),
+                             pos=pos, active=active)
+        out = [jax.lax.dynamic_update_index_in_dim(t, u, layer, 0)
+               if la is None else u.stack
+               for t, u, la in zip(leaves, treedef.flatten_up_to(upd),
+                                   spec.length_axes)]
+        return (h, jax.tree_util.tree_unflatten(treedef, out)), None
 
-    x, new_caches = jax.lax.scan(body, x, (params["blocks"], cache))
+    (x, cache), _ = jax.lax.scan(body, (x, cache),
+                                 (stacked, jnp.arange(n, dtype=jnp.int32)))
     x = common.norm_apply(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    return _lm_head(params, x, cfg), new_caches
+    return _lm_head(params, x, cfg), cache
 
 
 # ---------------------------------------------------------------------------
@@ -293,23 +389,6 @@ def encdec_prefill(params, inputs, cfg: ModelConfig, cache_len: int,
     else:
         x_last = x[jnp.arange(x.shape[0]), last_positions][:, None, :]
     return _lm_head(params, x_last, cfg), caches
-
-
-def encdec_decode_step(params, token_t, cache, pos, cfg: ModelConfig,
-                       active=None):
-    x = jnp.take(params["embed"], token_t, axis=0)
-    x = x + jnp.take(params["pos_embed"], pos, axis=0)[:, None, :]
-
-    def body(h, xs):
-        layer_params, layer_cache = xs
-        h2, new_cache, _ = blocks.dec_block(layer_params, h, cfg, memory=None,
-                                            mode="decode", cache=layer_cache,
-                                            pos=pos, active=active)
-        return h2, new_cache
-
-    x, new_caches = jax.lax.scan(body, x, (params["dec"], cache))
-    x = common.norm_apply(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    return _lm_head(params, x, cfg), new_caches
 
 
 # ---------------------------------------------------------------------------
