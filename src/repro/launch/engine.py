@@ -850,6 +850,10 @@ class ServeEngine:
         # decode occupancy: slots active over slot pages, summed over the
         # segments (and speculative rounds) dispatched
         self._occupancy = {"active_slot_segments": 0, "slot_segments": 0}
+        # the sampler branch each segment and speculative round takes:
+        # "sampled" when some row of its batch has a temperature (the
+        # host mirror of the predicate in sampling.sample)
+        self._sampler_path = {"argmax": 0, "sampled": 0}
         self._graphs: set = set()
         # -- resilience state (launch/resilience.py) --
         self._res = resilience if resilience is not None \
@@ -1646,8 +1650,9 @@ class ServeEngine:
         n_steps = self.segment_len
         self._graphs.add(("segment", bb, t_b, n_steps))
         active = int(np.sum(self._active))
+        sampled = int(np.sum(self._samp[1][:bb] > 0))
         sp = telemetry.span("engine.segment", active=active, bb=bb,
-                            t_b=t_b or 0)
+                            t_b=t_b or 0, sampled=sampled)
         try:
             fast = bb == self.n_slots and (t_b is None
                                            or t_b == self.max_cache_len)
@@ -1667,12 +1672,16 @@ class ServeEngine:
             sp.close()
             raise
         self._note_occupancy(active)
+        self._note_sampler(sampled)
         return _PendingSegment(bb=bb, seq=seq, tok=tok, pos=pos, bad=bad,
                                span=sp)
 
     def _note_occupancy(self, active: int) -> None:
         self._occupancy["active_slot_segments"] += active
         self._occupancy["slot_segments"] += self.n_slots
+
+    def _note_sampler(self, sampled: int) -> None:
+        self._sampler_path["sampled" if sampled else "argmax"] += 1
 
     def _finish_segment(self, p: "_PendingSegment",
                         clock: scheduler.Clock) -> None:
@@ -1800,6 +1809,7 @@ class ServeEngine:
             self._draft_cache = self._draft_spec.merge_live(
                 self._draft_cache, d_cache, bb, t_b)
         self._note_occupancy(int(np.sum(self._active)))
+        self._note_sampler(int(np.sum(self._samp[1][:bb] > 0)))
         self._pos[:bb] = np.asarray(pos_out)
         self._spec_harvest(np.asarray(g_seq), np.asarray(m),
                            np.asarray(bad), clock.now())
@@ -2656,6 +2666,7 @@ class ServeEngine:
             "enc_buckets": list(self.enc_buckets),
             "compactions": self.compactions,
             "occupancy": dict(self._occupancy),
+            "sampler_path": dict(self._sampler_path),
             "methods": {"admits": dict(self._method_admits)},
             "lowerings": dict(self._lowerings),
             "decode_bundle_lru": serve.decode_cache_info(),

@@ -17,7 +17,10 @@ decoding by making every sampled token a PURE FUNCTION of
   (`sample_host`, the replay-verification path);
 * greedy rows (temperature <= 0, the default) take the literal
   ``jnp.argmax`` path through a ``jnp.where`` select, keeping greedy
-  streams bit-identical to the pre-sampling engine.
+  streams bit-identical to the pre-sampling engine;
+* a batch in which no row samples skips the truncation and the draw
+  altogether (a ``lax.cond`` on the temperatures), so an all-greedy
+  decode step runs no full-vocabulary sort, softmax or Gumbel draw.
 
 The per-slot sampling state -- base key, temperature, top-k, top-p,
 prompt length -- is registered through `models/slot_state.py` as its own
@@ -145,7 +148,23 @@ def sample(last, key, temp, top_k, top_p, t):
     float32, mask everything outside the top-k/top-p truncation to -inf,
     and take the Gumbel-max argmax under the per-row key folded with the
     token index `t` [B].  Every op is per-row: a row's token is invariant
-    to batch composition (the engine's packing invariant)."""
+    to batch composition (the engine's packing invariant).
+
+    The truncation and draw run only when some row samples: the branch
+    is chosen on the device from `temp`, so one compiled program serves
+    greedy, sampled and mixed batches.  Under a mesh `temp` is
+    row-sharded and each shard chooses for its own rows; neither branch
+    holds a collective."""
+    return jax.lax.cond(jnp.any(temp > 0.0), _sampled, _greedy,
+                        last, key, temp, top_k, top_p, t)
+
+
+def _greedy(last, key, temp, top_k, top_p, t):
+    del key, temp, top_k, top_p, t
+    return jnp.argmax(last, axis=-1).astype(jnp.int32)
+
+
+def _sampled(last, key, temp, top_k, top_p, t):
     v = last.shape[-1]
     arg = jnp.argmax(last, axis=-1).astype(jnp.int32)
 

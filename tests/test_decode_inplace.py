@@ -12,9 +12,10 @@ length axis, whole layers on constant-size leaves.  Three guards:
 * the `decode_state_writes` counter of `ServeEngine.cache_info()`;
 * the compiled structure of an engine segment: no copy or fresh buffer of
   the whole stacked cache and no layer-sized `select` inside the step
-  loop, here on the CPU at reduced widths (tests/test_tpu_compile.py
-  checks the same at qwen1.5-0.5b widths on a described v5e, where the
-  segment's temp must also stay small).
+  loop, and no sort outside the sampler's conditional branch, here on
+  the CPU at reduced widths (tests/test_tpu_compile.py checks the same
+  at qwen1.5-0.5b widths on a described v5e, where the segment's temp
+  must also stay small).
 """
 import collections
 import dataclasses
@@ -253,14 +254,15 @@ def _computations(text):
     return comps, entry
 
 
-def _loop_ops(text):
-    """Counter of (shape, op) over every instruction the entry's while
-    loops run, nested loops and fusions included (custom calls by their
-    target)."""
+def _loop_computations(text, branches=False):
+    """The computations the entry's while loops run: their bodies and
+    conditions, nested loops, fusions and called computations, and with
+    `branches` the branches of conditionals too."""
     comps, entry = _computations(text)
     todo = [m.group(1) for ins in comps[entry]
             for m in re.finditer(r"body=%?([\w.\-]+)", ins)]
     assert todo, "the segment compiled to no loop"
+    calls = r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)"
     seen = set()
     while todo:
         c = todo.pop()
@@ -268,9 +270,22 @@ def _loop_ops(text):
             continue
         seen.add(c)
         for ins in comps[c]:
-            for m in re.finditer(
-                    r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", ins):
-                todo.append(m.group(1))
+            todo += [m.group(1) for m in re.finditer(calls, ins)]
+            if branches:
+                todo += re.findall(
+                    r"(?:true|false)_computation=%?([\w.\-]+)", ins)
+                m = re.search(r"branch_computations=\{([^}]*)\}", ins)
+                if m:
+                    todo += [b.strip().lstrip("%")
+                             for b in m.group(1).split(",")]
+    return comps, seen
+
+
+def _loop_ops(text):
+    """Counter of (shape, op) over every instruction the entry's while
+    loops run, nested loops and fusions included (custom calls by their
+    target)."""
+    comps, seen = _loop_computations(text)
     ops = collections.Counter()
     for c in seen:
         for ins in comps[c]:
@@ -317,7 +332,26 @@ def assert_no_cache_copies(text, cfg, n_slots, t):
     assert sum(v for (s, _), v in ops.items() if s == stack) > 0, ops
 
 
-def test_segment_loop_has_no_cache_copies_cpu():
-    cfg = _cfg("dense")
-    text = segment_lowered(cfg, 4, 64).compile().as_text()
-    assert_no_cache_copies(text, cfg, 4, 64)
+def assert_sort_only_in_branches(text):
+    """The step loop sorts only inside a conditional's branch: an
+    all-greedy step skips the sampler's full-vocabulary sort."""
+    def sorts(comps, names):
+        return sum(" sort(" in ins for c in names for ins in comps[c])
+
+    comps, plain = _loop_computations(text)
+    _, reach = _loop_computations(text, branches=True)
+    assert sorts(comps, plain) == 0
+    assert sorts(comps, reach - plain) > 0
+
+
+@pytest.fixture(scope="module")
+def segment_text_cpu():
+    return segment_lowered(_cfg("dense"), 4, 64).compile().as_text()
+
+
+def test_segment_loop_has_no_cache_copies_cpu(segment_text_cpu):
+    assert_no_cache_copies(segment_text_cpu, _cfg("dense"), 4, 64)
+
+
+def test_segment_loop_sorts_only_in_a_branch_cpu(segment_text_cpu):
+    assert_sort_only_in_branches(segment_text_cpu)

@@ -3,7 +3,11 @@ slot-page registration, greedy bit-parity with the pre-sampling engine,
 sampled-stream determinism (engine == static == repeat run), chaos-replay
 byte-identity, and prefix-cache warm-run identity.
 
-The contract under test is ISSUE/DESIGN sec. 12's purity obligation:
+The sampler itself is held bit for bit to its formula before the
+truncation and draw moved under a `lax.cond` (`_sample_before`), and the
+engine's `sampler_path` counter to the branch each dispatch takes.
+
+The contract under test is DESIGN sec. 12's purity obligation:
 every sampled token is a pure function of (seed, rid, token index,
 logits row), so recovery replay and warm admissions RECOMPUTE the same
 bytes instead of restoring sampler state."""
@@ -14,8 +18,8 @@ import pytest
 
 from repro import configs
 from repro.launch import resilience as res
-from repro.launch import sampling, scheduler, serve
-from repro.launch.engine import ServeEngine
+from repro.launch import sampling, scheduler, serve, telemetry
+from repro.launch.engine import ServeEngine, SpecDecodeConfig
 from repro.models import lm, slot_state
 from repro.quant.qtensor import quantize_tree_for_serving
 
@@ -238,3 +242,164 @@ def test_snapshot_restore_preserves_sampling(setup, tmp_path):
     assert eng2.restore(str(tmp_path)) == len(ref)
     out = eng2.run(clock=scheduler.FastForwardClock())
     _assert_bit_exact(ref, out)
+
+
+# ---------------------------------------------------------------------------
+# the sampler against its formula before the greedy branch
+# ---------------------------------------------------------------------------
+
+def _sample_before(last, key, temp, top_k, top_p, t):
+    """`sampling.sample` as it was before the truncation and draw moved
+    under a `lax.cond`: every batch sorted and drew, and greedy rows were
+    selected by the final `jnp.where`.  Kept as the oracle."""
+    v = last.shape[-1]
+    arg = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    x = last.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[:, None]
+    srt = jnp.sort(x, axis=-1)[:, ::-1]
+    kk = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+    thr_k = jnp.take_along_axis(srt, (kk - 1)[:, None], axis=-1)
+    probs = jax.nn.softmax(srt, axis=-1)
+    excl = jnp.cumsum(probs, axis=-1) - probs
+    kept = excl < top_p[:, None]
+    thr_p = jnp.min(jnp.where(kept, srt, jnp.inf), axis=-1, keepdims=True)
+    keep = (x >= thr_k) & (x >= thr_p)
+    kt = jax.vmap(jax.random.fold_in)(key, t)
+    gum = jax.vmap(
+        lambda k: jax.random.gumbel(k, (v,), jnp.float32))(kt)
+    smp = jnp.argmax(jnp.where(keep, x + gum, -jnp.inf),
+                     axis=-1).astype(jnp.int32)
+    return jnp.where(temp > 0.0, smp, arg)
+
+
+V = 203
+# per-row policy of each case: (temperature, top_k, top_p) by row index;
+# a greedy row has temperature 0
+POLICIES = {
+    "greedy": lambda r: (0.0, 0, 1.0),
+    "mixed": lambda r: ((0.0, 0, 1.0), (0.8, 5, 1.0), (1.3, 0, 0.9),
+                        (0.0, 7, 0.5))[r % 4],
+    "top_k": lambda r: (0.5 + 0.25 * (r % 3), (1, 5, V + 9)[r % 3], 1.0),
+    "top_p": lambda r: (0.7 + 0.1 * (r % 4), 0, (0.3, 0.9, 0.99)[r % 3]),
+    "top_k_top_p": lambda r: (0.9, 3 + r % 8, 0.8),
+    "temperature_only": lambda r: (0.6 + 0.2 * (r % 5), 0, 1.0),
+}
+SAMPLE_CASES = [(p, dt, b) for p in POLICIES
+                for dt in ("bfloat16", "float32") for b in (1, 8, 32)]
+
+
+def _logits(b, dtype, seed):
+    """Random rows with ties at the maximum and below it, -inf entries,
+    and (for b > 1) one all-NaN row."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(b, V)).astype(np.float32)
+    x[:, 10:20] = np.round(x[:, 10:20], 1)          # ties below the top
+    top = np.argmax(x, axis=-1)
+    x[np.arange(b), (top + 37) % V] = x[np.arange(b), top]  # tied maxima
+    x[rng.random((b, V)) < 0.1] = -np.inf
+    if b > 1:
+        x[b // 2] = np.nan
+    return jnp.asarray(x, dtype)
+
+
+@pytest.mark.parametrize("policy,dtype,b", SAMPLE_CASES,
+                         ids=[f"{p}-{dt}-b{b}" for p, dt, b in SAMPLE_CASES])
+def test_sample_matches_formula_before_greedy_branch(policy, dtype, b):
+    """Greedy, mixed and sampled batches give the same tokens, bit for
+    bit, as the sampler that sorted and drew for every batch."""
+    last = _logits(b, dtype, seed=b)
+    rows = [POLICIES[policy](r) for r in range(b)]
+    key = jnp.asarray([sampling.base_key(17, r) for r in range(b)],
+                      jnp.uint32)
+    temp = jnp.asarray([p[0] for p in rows], jnp.float32)
+    top_k = jnp.asarray([p[1] for p in rows], jnp.int32)
+    top_p = jnp.asarray([p[2] for p in rows], jnp.float32)
+    t = jnp.asarray(np.random.default_rng(b).integers(0, 500, b), jnp.int32)
+    got = jax.jit(sampling.sample)(last, key, temp, top_k, top_p, t)
+    want = jax.jit(_sample_before)(last, key, temp, top_k, top_p, t)
+    assert got.dtype == want.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _primitives(eqns):
+    """Names of the primitives some jaxpr equations run, sub-jaxprs
+    included."""
+    names = set()
+    for eqn in eqns:
+        names.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub.eqns)
+    return names
+
+
+def test_sample_sorts_only_inside_the_sampled_branch():
+    """The jaxpr of `sample`: no sort outside its one `cond`, and of the
+    cond's two branches only the sampled one sorts."""
+    b = 4
+    jaxpr = jax.make_jaxpr(sampling.sample)(
+        jnp.zeros((b, V), jnp.float32), jnp.zeros((b, 2), jnp.uint32),
+        jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32)).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    assert "sort" not in _primitives(e for e in jaxpr.eqns
+                                     if e.primitive.name != "cond")
+    greedy, sampled = (_primitives(br.jaxpr.eqns)
+                       for br in conds[0].params["branches"])
+    assert "sort" not in greedy and "argmax" in greedy
+    assert "sort" in sampled
+
+
+# ---------------------------------------------------------------------------
+# the engine's sampler_path counter
+# ---------------------------------------------------------------------------
+
+SP_ONE = scheduler.SamplingParams(temperature=0.8, top_k=5, seed=2)
+PATH_CASES = {
+    # (spec decode, sampling of each request, dispatches that sample):
+    # the sampled request wants 3 tokens, its first from the prefill, so
+    # it shares one segment (4 steps) or one round (k + 1 = 3) with the
+    # others, and the dispatches after it are all greedy
+    "greedy": (False, (None,) * 4, 0),
+    "one_sampled": (False, (None, SP_ONE, None, None), 1),
+    "spec_greedy": (True, (None,) * 4, 0),
+    "spec_one_sampled": (True, (None, SP_ONE, None, None), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(PATH_CASES))
+def test_sampler_path_counts_each_dispatch(setup, case):
+    """`cache_info()["sampler_path"]` counts every segment (or
+    speculative round) once: "argmax" when no row of its batch samples,
+    "sampled" when one does; each `engine.segment` span carries how many
+    of its rows sample."""
+    cfg, params = setup
+    spec, mix, sampled = PATH_CASES[case]
+    gens = (12, 3, 10, 14)
+    reqs = [scheduler.Request(
+        rid=i, prompt=np.asarray(jax.random.randint(
+            jax.random.PRNGKey(30 + i), (6 + i,), 0, cfg.vocab)),
+        max_new_tokens=g, sampling=p)
+        for i, (g, p) in enumerate(zip(gens, mix))]
+    kw = {"spec_decode": SpecDecodeConfig(draft_params=params,
+                                          draft_cfg=cfg, k=2)} \
+        if spec else {}
+    eng = _engine(cfg, params, chaos=None, **kw)
+    t0 = telemetry.now()
+    eng.run(reqs, clock=scheduler.FastForwardClock())
+    info = eng.cache_info()
+    path = info["sampler_path"]
+    n = info["spec_decode"]["rounds"] if spec \
+        else info["dispatch_sites"]["segment"]
+    assert n > 1
+    assert path == {"argmax": n - sampled, "sampled": sampled}
+    if not spec:
+        segs = [s for s in telemetry.RECORDER.spans("engine.segment")
+                if s.start >= t0]
+        assert len(segs) == n
+        assert all(0 <= s.counts["sampled"] <= s.counts["active"]
+                   for s in segs)
+        assert sum(s.counts["sampled"] > 0 for s in segs) \
+            == path["sampled"]

@@ -22,7 +22,9 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs
 from repro.kernels import (autotune, mul4, muladd2, packed_matmul,
                            quant_matmul, simd_add)
-from test_decode_inplace import assert_no_cache_copies, segment_lowered
+from test_decode_inplace import (assert_no_cache_copies,
+                                 assert_sort_only_in_branches,
+                                 segment_lowered)
 
 GEMMS = [(8, 1024, 2816), (8, 2816, 1024), (512, 1024, 3072),
          (512, 2816, 1024)]
@@ -94,14 +96,28 @@ def test_mul4_compiles(one_chip, rows, cols):
              one_chip, ((4, rows, cols), jnp.int8), ((rows, cols), jnp.int8))
 
 
-def test_segment_loop_has_no_cache_copies(one_chip):
+@pytest.fixture(scope="module")
+def qwen_segment(one_chip):
     """The engine segment at qwen1.5-0.5b widths, 8 slots x 1024
-    positions: the step loop holds no copy or fresh buffer of the whole
-    stacked cache and no layer-sized select (test_decode_inplace.py), and
-    the segment's temp stays under 2.5 GB (4.9 GB when the cache was the
-    layer scan's xs/ys)."""
+    positions, compiled once for the described v5e."""
     cfg = dataclasses.replace(configs.get_config("qwen1.5-0.5b"),
                               attn_q_chunk=256)
-    compiled = segment_lowered(cfg, 8, 1024, one_chip).compile()
+    return cfg, segment_lowered(cfg, 8, 1024, one_chip).compile()
+
+
+def test_segment_loop_has_no_cache_copies(qwen_segment):
+    """The step loop holds no copy or fresh buffer of the whole stacked
+    cache and no layer-sized select (test_decode_inplace.py), and the
+    segment's temp stays under 2.5 GB (4.9 GB when the cache was the
+    layer scan's xs/ys)."""
+    cfg, compiled = qwen_segment
     assert_no_cache_copies(compiled.as_text(), cfg, 8, 1024)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_segment_loop_sorts_only_in_a_branch(qwen_segment):
+    """The TPU compiler keeps the sampler's conditional: the step loop
+    runs the [8, 151936] sort only inside a branch, so an all-greedy
+    step skips it."""
+    _, compiled = qwen_segment
+    assert_sort_only_in_branches(compiled.as_text())
